@@ -81,7 +81,7 @@ def run(scales=SCALES, repeats: int = 3, json_path: str = "BENCH_pr6.json",
         # the int64 baseline needs real 64-bit arrays, which JAX only
         # provides under the x64 switch; the whole baseline branch
         # (build + run) lives inside the context so nothing narrows.
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             g64 = _build(scale, "int64")
             src = _source(g64)
             base_ms, base_out, base_bpe = {}, {}, None

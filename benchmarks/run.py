@@ -54,6 +54,8 @@ def main() -> None:
                     help="write all emitted rows (backend column included) "
                          "as JSON")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
     if args.backend:
         os.environ["REPRO_BACKEND"] = args.backend
     mods = [args.only] if args.only else MODULES

@@ -13,7 +13,13 @@ Backends:
              portable default; XLA fuses the functor into the sweep.
   "pallas" — hand-written Pallas TPU kernels from ``repro.kernels``
              (interpret mode off-TPU, which is the correctness contract).
-  "auto"   — resolves to "pallas" on a TPU backend, "xla" elsewhere.
+             On a TPU the kernels must compile natively, and the TPU
+             lowering refuses the graph kernels listed in
+             ``PALLAS_TPU_REFUSED``: asking for "pallas" there raises
+             ``PallasUnavailableError`` instead of running interpreted or
+             dropping to "xla".
+  "auto"   — "pallas" on a TPU once no graph kernel is refused there,
+             "xla" otherwise (today: "xla" everywhere).
 
 Placements (the second registry dimension, paper §8.2.1 scale-out):
   "single"  — one device holds the whole graph (the default).
@@ -157,6 +163,10 @@ _REGISTRY: dict[tuple[str, str, str], Callable] = {}
 # (op, backend, placement) combination works under every storage plan.
 _ENCODINGS: dict[tuple[str, str, str], tuple] = {}
 
+# op -> registry key of the provider its latest dispatch resolved to,
+# fallbacks included: what actually served the op (``served()``).
+_SERVED: dict[str, tuple[str, str, str]] = {}
+
 # Backends whose implementations live in a module that registers itself on
 # import — imported lazily so `import repro.core` never pulls in Pallas.
 _LAZY_PROVIDERS = {PALLAS: "repro.kernels.ops"}
@@ -202,9 +212,44 @@ def _check_placement(name: str) -> str:
     return name
 
 
-def _auto() -> str:
+# Pallas graph kernels the TPU compiler (Mosaic, jax 0.9, TPU v5e) refuses,
+# with its reason. tests/test_tpu_compile.py holds one strict xfail per
+# entry, so a kernel that starts compiling turns the suite red until its
+# entry goes; once the table is empty "auto" picks "pallas" on a TPU again.
+PALLAS_TPU_REFUSED = {
+    "advance_fused": "NotImplementedError: Only 2D gather is supported",
+    "advance_filter_fused": "NotImplementedError: Only 2D gather is supported",
+    "lb_expand": "NotImplementedError: Only 2D gather is supported",
+    "segment_search": "NotImplementedError: Only 2D gather is supported",
+    "semiring_spmv": "AssertionError in the gather lowering (k=1); the "
+                     "(nx, 1) x-column block is not (8, 128)-aligned (k>1)",
+    "filter_compact": "ValueError: the rank-1 (1,) counts block is not a "
+                      "multiple of the 128-lane tiling (widened, the body "
+                      "then needs cumsum, which the lowering lacks)",
+}
+
+
+class PallasUnavailableError(RuntimeError):
+    """The pallas backend was asked for on a TPU whose compiler refuses
+    its kernels (see ``PALLAS_TPU_REFUSED``)."""
+
+
+def _on_tpu() -> bool:
     import jax
-    return PALLAS if jax.default_backend() == "tpu" else XLA
+    return jax.default_backend() == "tpu"
+
+
+def _auto() -> str:
+    return PALLAS if _on_tpu() and not PALLAS_TPU_REFUSED else XLA
+
+
+def _require_pallas() -> None:
+    if PALLAS_TPU_REFUSED and _on_tpu():
+        refused = "; ".join(f"{k}: {why}"
+                            for k, why in PALLAS_TPU_REFUSED.items())
+        raise PallasUnavailableError(
+            f"backend 'pallas' cannot run on this TPU: the TPU compiler "
+            f"refuses its graph kernels ({refused}); use 'xla' or 'auto'")
 
 
 def resolve(backend: Optional[str] = None,
@@ -231,7 +276,11 @@ def resolve(backend: Optional[str] = None,
     if backend is None:
         backend = os.environ.get(ENV_VAR) or XLA
     _check(backend)
-    return _auto() if backend == AUTO else backend
+    if backend == AUTO:
+        return _auto()
+    if backend == PALLAS:
+        _require_pallas()
+    return backend
 
 
 def resolve_placement(placement: Optional[str] = None) -> str:
@@ -405,7 +454,15 @@ def _lookup(op: str, bk: str, pl: str) -> tuple[tuple, Callable]:
                        f"single-device path")
         raise ProviderMissError(op, bk, pl,
                                 nearest=_nearest_key(op, bk, pl))
+    _SERVED[op] = key
     return key, impl
+
+
+def served() -> dict[str, tuple[str, str, str]]:
+    """op -> (op, backend, placement) of the provider that last served
+    it in this process. Dispatch runs at trace time, so this records the
+    providers compiled into the programs that ran."""
+    return dict(_SERVED)
 
 
 def _fault_plan():

@@ -77,7 +77,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from . import backend as B
 from .partition import (Partitioned2DGraph, PartitionedGraph,
@@ -258,12 +258,12 @@ def _spmm_sharded(offsets, indices, values, x, sr, ell_width, mask,
     if values is None:
         run = shard_map(lambda ro, ci, xg: local_rows(ro, ci, None, xg),
                         mesh=mesh, in_specs=(part, part, rep),
-                        out_specs=part, check_rep=False)
+                        out_specs=part, check_vma=False)
         y = run(offsets, indices, x)
     else:
         run = shard_map(local_rows, mesh=mesh,
                         in_specs=(part, part, part, rep),
-                        out_specs=part, check_rep=False)
+                        out_specs=part, check_vma=False)
         y = run(offsets, indices, values, x)
     y = y[:n]                                   # drop tail-part padding rows
     if mask is not None:
@@ -309,12 +309,12 @@ def _spmv_sharded(offsets, indices, values, x, sr, ell_width, mask,
     if values is None:
         run = shard_map(lambda ro, ci, xg: local_rows(ro, ci, None, xg),
                         mesh=mesh, in_specs=(part, part, rep),
-                        out_specs=part, check_rep=False)
+                        out_specs=part, check_vma=False)
         y = run(offsets, indices, x)
     else:
         run = shard_map(local_rows, mesh=mesh,
                         in_specs=(part, part, part, rep),
-                        out_specs=part, check_rep=False)
+                        out_specs=part, check_vma=False)
         y = run(offsets, indices, values, x)
     y = y[:n]
     if mask is not None:
@@ -387,7 +387,7 @@ def _mxm_sharded(a_off, a_idx, a_vals, bt_off, bt_idx, bt_vals,
 
     run = shard_map(local, mesh=mesh,
                     in_specs=(part, part, part, rep, rep, rep, rep, rep),
-                    out_specs=rep, check_rep=False)
+                    out_specs=rep, check_vma=False)
     return run(a_off, a_idx, av_in, bt_off, bt_idx, btv_in, base,
                probe_rows)
 
@@ -538,12 +538,12 @@ def _spmv_2d(offsets, store, values, x, sr, ell_width, mask,
     if values is None:
         run = shard_map(lambda ro, st, xg: local(ro, st, None, xg),
                         mesh=mesh, in_specs=(blk, blk, rep),
-                        out_specs=P(row_ax), check_rep=False)
+                        out_specs=P(row_ax), check_vma=False)
         y = run(offsets, store, x)
     else:
         run = shard_map(local, mesh=mesh,
                         in_specs=(blk, blk, blk, rep),
-                        out_specs=P(row_ax), check_rep=False)
+                        out_specs=P(row_ax), check_vma=False)
         y = run(offsets, store, values, x)
     y = y[:n]
     if mask is not None:
@@ -584,12 +584,12 @@ def _spmm_2d(offsets, store, values, x, sr, ell_width, mask,
     if values is None:
         run = shard_map(lambda ro, st, xg: local(ro, st, None, xg),
                         mesh=mesh, in_specs=(blk, blk, rep),
-                        out_specs=P(row_ax), check_rep=False)
+                        out_specs=P(row_ax), check_vma=False)
         y = run(offsets, store, x)
     else:
         run = shard_map(local, mesh=mesh,
                         in_specs=(blk, blk, blk, rep),
-                        out_specs=P(row_ax), check_rep=False)
+                        out_specs=P(row_ax), check_vma=False)
         y = run(offsets, store, values, x)
     y = y[:n]
     if mask is not None:
@@ -649,7 +649,7 @@ def _mxm_2d(a_off, a_store, a_vals, bt_off, bt_idx, bt_vals,
 
     run = shard_map(local, mesh=mesh,
                     in_specs=(blk, blk, blk, rep, rep, rep, rep, rep),
-                    out_specs=rep, check_rep=False)
+                    out_specs=rep, check_vma=False)
     return run(a_off, a_idx, av_in, bt_off, bt_idx, btv_in, base,
                probe_rows)
 
@@ -670,7 +670,7 @@ def _bfs_dist_impl(ro, ci, base, src, *, n: int, vpp: int, mesh: Mesh,
         shard_map, mesh=mesh,
         in_specs=(part, part, part, rep),
         out_specs=(rep, rep),
-        check_rep=False)
+        check_vma=False)
     def run(ro_s, ci_s, base_s, src_v):
         local_ro = ro_s[0]
         local_ci = ci_s[0]
@@ -713,7 +713,7 @@ def _bfs_2d_impl(ro, ci, row_base, col_base, src, *, n: int, vpr: int,
         shard_map, mesh=mesh,
         in_specs=(blk, blk, P(row_ax), P(col_ax), rep),
         out_specs=(rep, rep),
-        check_rep=False)
+        check_vma=False)
     def run(ro_s, ci_s, rb_s, cb_s, src_v):
         block_ro, block_ci = ro_s[0, 0], ci_s[0, 0]
         my_rb, my_cb = rb_s[0], cb_s[0]
@@ -780,7 +780,7 @@ def _sssp_dist_impl(ro, ci, ev, base, src, delta, *, n: int, vpp: int,
         shard_map, mesh=mesh,
         in_specs=(part, part, part, part, rep, rep),
         out_specs=(rep, rep),
-        check_rep=False)
+        check_vma=False)
     def run(ro_s, ci_s, ev_s, base_s, src_v, delta_v):
         local_ro, local_ci, local_ev = ro_s[0], ci_s[0], ev_s[0]
         my_base = base_s[0]
@@ -857,7 +857,7 @@ def _sssp_2d_impl(ro, ci, ev, row_base, col_base, src, delta, *, n: int,
         shard_map, mesh=mesh,
         in_specs=(blk, blk, blk, P(row_ax), P(col_ax), rep, rep),
         out_specs=(rep, rep),
-        check_rep=False)
+        check_vma=False)
     def run(ro_s, ci_s, ev_s, rb_s, cb_s, src_v, delta_v):
         block_ro, block_ci, block_ev = ro_s[0, 0], ci_s[0, 0], ev_s[0, 0]
         my_rb, my_cb = rb_s[0], cb_s[0]
@@ -965,7 +965,7 @@ def _cc_dist_impl(ro, ci, base, *, n: int, vpp: int, mesh: Mesh, axis: str):
         shard_map, mesh=mesh,
         in_specs=(part, part, part),
         out_specs=(rep, rep),
-        check_rep=False)
+        check_vma=False)
     def run(ro_s, ci_s, base_s):
         local_ro, local_ci = ro_s[0], ci_s[0]
         my_base = base_s[0]
@@ -1022,7 +1022,7 @@ def _cc_2d_impl(ro, ci, row_base, *, n: int, vpr: int, mesh: Mesh,
         shard_map, mesh=mesh,
         in_specs=(blk, blk, P(row_ax)),
         out_specs=(rep, rep),
-        check_rep=False)
+        check_vma=False)
     def run(ro_s, ci_s, rb_s):
         block_ro, block_ci = ro_s[0, 0], ci_s[0, 0]
         my_rb = rb_s[0]

@@ -47,8 +47,9 @@ from dataclasses import dataclass
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from . import storage as S
 from .graph import Graph
@@ -82,6 +83,18 @@ def check_mesh_axes(mesh, axes, shape) -> None:
             raise ValueError(
                 f"mesh axis {ax!r} (size {sizes.get(ax)}) must match "
                 f"the 2-D partition's {tuple(shape)} blocks")
+
+
+def _placer(mesh, spec):
+    """Host array -> device array split over ``mesh`` by ``spec``: block
+    p of the leading (stacked) axis lands on the device that owns it, so
+    shard_map runs on it in place. None passes through."""
+    sharding = NamedSharding(mesh, spec)
+
+    def put(a):
+        return None if a is None else jax.device_put(a, sharding)
+
+    return put
 
 
 def _slice_rows(ro: np.ndarray, ci: np.ndarray, ev: Optional[np.ndarray],
@@ -172,8 +185,9 @@ class PartitionedGraph:
 
     def shard(self, mesh, axis: str = "graph") -> "ShardedGraph":
         """Device-side view for the sharded registry providers. ``mesh``
-        must carry a 1-D axis ``axis`` of size ``num_parts``. Views are
-        cached per (mesh, axis): repeated calls (every query of a
+        must carry a 1-D axis ``axis`` of size ``num_parts``; part p of
+        every stacked array lives on the p-th device of that axis. Views
+        are cached per (mesh, axis): repeated calls (every query of a
         serving loop goes through here) reuse one set of device arrays
         instead of re-uploading the partition."""
         check_mesh_axis(mesh, axis, self.num_parts)
@@ -184,18 +198,15 @@ class PartitionedGraph:
         key = (mesh, axis)
         if key in cache:
             return cache[key]
+        put = _placer(mesh, P(axis))
         cache[key] = ShardedGraph(
-            row_offsets=jnp.asarray(self.row_offsets),
-            col_indices=jnp.asarray(self.col_indices),
-            edge_values=(jnp.asarray(self.edge_values)
-                         if self.edge_values is not None else None),
-            csc_offsets=(jnp.asarray(self.csc_row_offsets)
-                         if self.csc_row_offsets is not None else None),
-            csc_indices=(jnp.asarray(self.csc_col_indices)
-                         if self.csc_col_indices is not None else None),
-            csc_edge_values=(jnp.asarray(self.csc_edge_values)
-                             if self.csc_edge_values is not None else None),
-            vertex_base=jnp.asarray(self.vertex_base),
+            row_offsets=put(self.row_offsets),
+            col_indices=put(self.col_indices),
+            edge_values=put(self.edge_values),
+            csc_offsets=put(self.csc_row_offsets),
+            csc_indices=put(self.csc_col_indices),
+            csc_edge_values=put(self.csc_edge_values),
+            vertex_base=put(self.vertex_base),
             n=self.n, m=self.m, verts_per_part=self.verts_per_part,
             mesh=mesh, axis=axis,
             ell_width=(self.source.ell_width
@@ -525,8 +536,9 @@ class Partitioned2DGraph:
 
     def shard(self, mesh, axes=("row", "col")) -> "Sharded2DGraph":
         """Device-side view for the 2-D registry providers. ``mesh``
-        must carry axes ``axes`` of sizes (R, C). Cached per
-        (mesh, axes) like the 1-D container."""
+        must carry axes ``axes`` of sizes (R, C); block (i, j) lives on
+        mesh device (i, j). Cached per (mesh, axes) like the 1-D
+        container."""
         axes = tuple(axes)
         check_mesh_axes(mesh, axes, (self.rows, self.cols))
         cache = self.__dict__.get("_shard_cache")
@@ -544,26 +556,22 @@ class Partitioned2DGraph:
                                    (self.rows, self.cols,
                                     chunk_ro.shape[1])).copy()
 
+        put = _placer(mesh, P(*axes))
         cache[key] = Sharded2DGraph(
-            row_offsets=jnp.asarray(self.row_offsets),
-            col_indices=jnp.asarray(self.col_indices),
-            edge_values=(jnp.asarray(self.edge_values)
-                         if self.edge_values is not None else None),
-            edge_pos=jnp.asarray(self.edge_pos),
-            chunk_offsets=jnp.asarray(repl(self.chunk_offsets)),
-            csc_offsets=(jnp.asarray(self.csc_row_offsets)
-                         if self.csc_row_offsets is not None else None),
-            csc_indices=(jnp.asarray(self.csc_col_indices)
-                         if self.csc_col_indices is not None else None),
-            csc_edge_values=(jnp.asarray(self.csc_edge_values)
-                             if self.csc_edge_values is not None else None),
-            csc_edge_pos=(jnp.asarray(self.csc_edge_pos)
-                          if self.csc_edge_pos is not None else None),
-            csc_chunk_offsets=(jnp.asarray(repl(self.csc_chunk_offsets))
+            row_offsets=put(self.row_offsets),
+            col_indices=put(self.col_indices),
+            edge_values=put(self.edge_values),
+            edge_pos=put(self.edge_pos),
+            chunk_offsets=put(repl(self.chunk_offsets)),
+            csc_offsets=put(self.csc_row_offsets),
+            csc_indices=put(self.csc_col_indices),
+            csc_edge_values=put(self.csc_edge_values),
+            csc_edge_pos=put(self.csc_edge_pos),
+            csc_chunk_offsets=(put(repl(self.csc_chunk_offsets))
                                if self.csc_chunk_offsets is not None
                                else None),
-            row_base=jnp.asarray(self.row_base),
-            col_base=jnp.asarray(self.col_base),
+            row_base=_placer(mesh, P(axes[0]))(self.row_base),
+            col_base=_placer(mesh, P(axes[1]))(self.col_base),
             n=self.n, m=self.m, rows=self.rows, cols=self.cols,
             vpr=self.vpr, vpc=self.vpc,
             chunk_emax=self.chunk_emax,

@@ -7,8 +7,6 @@ validation pass.
 """
 from __future__ import annotations
 
-import heapq
-
 import numpy as np
 
 
@@ -18,45 +16,54 @@ def _csr(graph):
             else np.asarray(graph.edge_values))
 
 
+def _out_edges(ro: np.ndarray, rows: np.ndarray):
+    """Edge positions of every out-edge of ``rows`` (row-major), and the
+    per-row counts — the vectorized CSR gather the set-at-a-time oracles
+    below share."""
+    starts = ro[rows].astype(np.int64)
+    counts = ro[rows + 1].astype(np.int64) - starts
+    before = np.cumsum(counts) - counts
+    pos = (np.repeat(starts - before, counts)
+           + np.arange(int(counts.sum()), dtype=np.int64))
+    return pos, counts
+
+
 def bfs_ref(graph, src: int) -> np.ndarray:
-    """Breadth-first search depths (-1 = unreachable)."""
+    """Breadth-first search depths (-1 = unreachable), one level at a
+    time: the next level is every unvisited out-neighbor of this one."""
     ro, ci, _ = _csr(graph)
     n = len(ro) - 1
     depth = np.full(n, -1, dtype=np.int32)
     depth[src] = 0
-    frontier = [src]
+    frontier = np.asarray([src], np.int64)
     d = 0
-    while frontier:
+    while len(frontier):
         d += 1
-        nxt = []
-        for u in frontier:
-            for e in range(ro[u], ro[u + 1]):
-                v = ci[e]
-                if depth[v] < 0:
-                    depth[v] = d
-                    nxt.append(v)
-        frontier = nxt
+        pos, _ = _out_edges(ro, frontier)
+        nbr = ci[pos]
+        frontier = np.unique(nbr[depth[nbr] < 0])
+        depth[frontier] = d
     return depth
 
 
 def sssp_ref(graph, src: int) -> np.ndarray:
-    """Dijkstra distances (inf = unreachable)."""
+    """Shortest-path distances (inf = unreachable) by Bellman-Ford over
+    the set of vertices improved in the previous round, in float64."""
     ro, ci, w = _csr(graph)
     assert w is not None, "sssp needs edge weights"
     n = len(ro) - 1
+    w = np.asarray(w, np.float64)
     dist = np.full(n, np.inf, dtype=np.float64)
     dist[src] = 0.0
-    heap = [(0.0, src)]
-    while heap:
-        d, u = heapq.heappop(heap)
-        if d > dist[u]:
-            continue
-        for e in range(ro[u], ro[u + 1]):
-            v = ci[e]
-            nd = d + w[e]
-            if nd < dist[v]:
-                dist[v] = nd
-                heapq.heappush(heap, (nd, v))
+    active = np.asarray([src], np.int64)
+    while len(active):
+        pos, counts = _out_edges(ro, active)
+        nbr = ci[pos]
+        cand = np.repeat(dist[active], counts) + w[pos]
+        better = cand < dist[nbr]
+        nbr, cand = nbr[better], cand[better]
+        np.minimum.at(dist, nbr, cand)
+        active = np.unique(nbr)
     return dist.astype(np.float32)
 
 
@@ -73,8 +80,7 @@ def pagerank_ref(graph, damping: float = 0.85, iters: int = 20,
     src = np.repeat(np.arange(n), deg)
     for _ in range(iters):
         contrib = np.where(deg > 0, pr / np.maximum(deg, 1), 0.0)
-        nxt = np.zeros(n)
-        np.add.at(nxt, ci, contrib[src])
+        nxt = np.bincount(ci, weights=contrib[src], minlength=n)
         dangling = pr[deg == 0].sum() / n
         new = (1 - damping) / n + damping * (nxt + dangling)
         if tol > 0 and np.abs(new - pr).max() < tol:
@@ -85,24 +91,28 @@ def pagerank_ref(graph, damping: float = 0.85, iters: int = 20,
 
 
 def cc_ref(graph) -> np.ndarray:
-    """Connected-component labels (union-find; labels = min vertex id of
-    component, then relabeled to root representative)."""
+    """Weakly connected components labelled by their smallest vertex id.
+
+    Every edge hooks the larger of its endpoints' labels onto the
+    smaller (both directions), then labels pointer-jump to their roots;
+    repeat until no edge joins two labels."""
     ro, ci, _ = _csr(graph)
     n = len(ro) - 1
-    parent = np.arange(n)
-
-    def find(x):
-        while parent[x] != x:
-            parent[x] = parent[parent[x]]
-            x = parent[x]
-        return x
-
     src = np.repeat(np.arange(n), np.diff(ro))
-    for u, v in zip(src, ci):
-        ru, rv = find(u), find(v)
-        if ru != rv:
-            parent[max(ru, rv)] = min(ru, rv)
-    return np.array([find(x) for x in range(n)], dtype=np.int32)
+    dst = np.asarray(ci, np.int64)
+    label = np.arange(n)
+    while True:
+        lu, lv = label[src], label[dst]
+        cross = lu != lv
+        if not cross.any():
+            return label.astype(np.int32)
+        lu, lv = lu[cross], lv[cross]
+        np.minimum.at(label, np.maximum(lu, lv), np.minimum(lu, lv))
+        while True:
+            jumped = label[label]
+            if np.array_equal(jumped, label):
+                break
+            label = jumped
 
 
 def bc_ref(graph, src: int) -> np.ndarray:

@@ -1,8 +1,9 @@
 """Graceful-degradation ladder for graph queries.
 
-When a batch keeps failing after retries, the serving loop walks down a
-ladder of cheaper/safer configurations instead of failing the queries
-outright:
+When a batch keeps failing after retries under an installed fault plan
+(``repro.ft.inject``), the serving loop walks down a ladder of
+cheaper/safer configurations instead of failing the queries outright;
+outside a chaos run it retries the requested configuration only:
 
   backend    pallas → xla              (same placement, same results)
   placement  2d → sharded → single     (same results, less parallelism)

@@ -1,18 +1,9 @@
-"""Compatibility shims over the drifting jax mesh/sharding surface.
+"""The jax mesh/sharding spellings the model/launch code uses, in one place.
 
-The mesh API has been renamed/moved repeatedly across jax releases:
-``jax.sharding.get_abstract_mesh``, ``jax.set_mesh``,
-``jax.sharding.AxisType`` and the ``axis_types=`` kwarg of
-``jax.make_mesh`` all exist only on newer releases, while older ones
-spell the same concepts through the classic ``with mesh:`` resource
-environment. Model/launch code calling the new spellings directly
-fails with ``AttributeError`` the moment the installed jax moves —
-that failure took out 55 seed tests.
-
-Every shim here resolves the new API with ``getattr`` first and falls
-back to an equivalent older-jax formulation, so the same call sites run
-on both sides of the rename. Only this module is allowed to touch
-``jax._src`` — keep the fallback surface in one place.
+Written against the installed jax (0.9): ``jax.make_mesh`` with explicit
+``axis_types=``, ``jax.set_mesh`` and ``jax.sharding.get_abstract_mesh``.
+Call sites import these names from here rather than from jax so that
+the next rename is fixed in one module.
 """
 from __future__ import annotations
 
@@ -20,69 +11,29 @@ import jax
 
 
 def axis_type_auto():
-    """``jax.sharding.AxisType.Auto`` where it exists, else None."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return getattr(axis_type, "Auto", None) if axis_type is not None else None
+    """``jax.sharding.AxisType.Auto``."""
+    return jax.sharding.AxisType.Auto
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types when the kwarg exists.
-
-    Older jax has no ``axis_types=`` (every axis is implicitly "auto");
-    newer jax wants it spelled out for the explicit-sharding rollout.
-    """
+    """``jax.make_mesh`` with every axis typed Auto."""
     kwargs = {} if devices is None else {"devices": devices}
-    auto = axis_type_auto()
-    if auto is not None:
-        try:
-            return jax.make_mesh(axis_shapes, axis_names,
-                                 axis_types=(auto,) * len(axis_names),
-                                 **kwargs)
-        except TypeError:
-            pass                      # AxisType exists but the kwarg doesn't
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(axis_type_auto(),) * len(axis_names),
+                         **kwargs)
 
 
 def set_mesh(mesh):
-    """Context manager activating ``mesh`` for the enclosed block.
-
-    Newer jax: ``jax.set_mesh``. Older jax: the ``Mesh`` object is
-    itself the context manager (the classic resource environment), and
-    ``get_abstract_mesh`` below reads through it.
-    """
-    fn = getattr(jax, "set_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh
+    """Context manager activating ``mesh`` for the enclosed block."""
+    return jax.set_mesh(mesh)
 
 
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every jax release
-    (older jax returns a one-element list of dicts, newer the dict)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """``Compiled.cost_analysis()`` as a flat dict."""
+    return compiled.cost_analysis()
 
 
 def get_abstract_mesh():
-    """The active abstract mesh, or None when no mesh is active.
-
-    Callers must handle both None and a mesh whose ``.empty`` is True
-    (the two "no mesh" spellings across releases).
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    from jax._src import mesh as _mesh_lib
-    getter = getattr(_mesh_lib, "get_abstract_mesh", None)
-    if getter is not None:
-        got = getter()
-        if isinstance(got, _mesh_lib.AbstractMesh):
-            return got
-    # classic resource env: `with mesh:` / the set_mesh fallback above
-    env = getattr(_mesh_lib.thread_resources, "env", None)
-    physical = getattr(env, "physical_mesh", None)
-    if physical is not None and not physical.empty:
-        return physical.abstract_mesh
-    return None
+    """The active abstract mesh. Callers treat a mesh whose ``.empty``
+    is True (and None) as "no mesh active"."""
+    return jax.sharding.get_abstract_mesh()

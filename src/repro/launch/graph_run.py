@@ -27,7 +27,7 @@ import time
 import jax
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.core import backend as B
 from repro.core import graph as G
 from repro.core import ref as R
@@ -246,6 +246,11 @@ def main(argv=None):
                     help="write phase spans as Chrome trace-event JSON "
                          "(open at ui.perfetto.dev)")
     args = ap.parse_args(argv)
+    try:
+        bk = B.resolve(args.backend)
+    except B.PallasUnavailableError as exc:
+        raise SystemExit(str(exc))
+    compile_cache.enable()
 
     if args.trace:
         obs.reset()
@@ -266,20 +271,25 @@ def main(argv=None):
                if args.sources else None)
     log.info(f"{args.graph} scale={args.scale}: n={g.num_vertices} "
              f"m={g.num_edges} max_deg={deg.max()} "
-             f"src={sources if sources else src} "
-             f"backend={B.resolve(args.backend)}")
+             f"src={sources if sources else src} backend={bk}")
 
     failures = 0
+    results = []
     for name in args.primitives.split(","):
         name = name.strip()
+        t0 = time.monotonic()
         with obs.span(f"run:{name}", category="dispatch",
-                      args={"backend": B.resolve(args.backend)}):
-            dt, mteps, ok, bk = run_primitive(
-                name, g, src, args.validate, args.backend,
+                      args={"backend": bk}):
+            dt, mteps, ok, _ = run_primitive(
+                name, g, src, args.validate, bk,
                 sources=sources, hops=args.hops)
+        validate_s = time.monotonic() - t0 - dt
         status = "" if ok is None else ("  PASS" if ok else "  FAIL")
         log.info(f"{name:9s} {dt*1000:9.2f} ms  {mteps:9.2f} MTEPS"
                  f"  backend={bk}{status}")
+        results.append({"primitive": name, "seconds": dt, "mteps": mteps,
+                        "valid": ok, "validate_seconds": validate_s,
+                        "backend": bk})
         if ok is False:
             failures += 1
         if args.stats:
@@ -297,6 +307,7 @@ def main(argv=None):
         log.info(f"wrote {n_ev} trace events to {args.trace}")
     if failures:
         raise SystemExit(f"{failures} primitives failed validation")
+    return results
 
 
 if __name__ == "__main__":

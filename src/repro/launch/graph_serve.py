@@ -58,8 +58,7 @@ import time
 import jax
 import numpy as np
 
-from repro import obs
-from repro import ft
+from repro import compile_cache, ft, obs
 from repro.core import backend as B
 from repro.core import ref as R
 from repro.core.storage import resident_bytes
@@ -367,10 +366,13 @@ def serve_mixed(g, queries, batch: int, backend: str, hops: int = 3,
       * ``admission`` bounds the slot queues — arrivals over the cap are
         shed with a structured rejection;
       * batch dispatch runs under ``retry`` (exponential backoff,
-        deterministic jitter) escalating through the ``repro.ft.degrade``
-        ladder (pallas→xla, placement→single, reach reduced-hop); a
-        downgraded batch's queries are stamped ``degraded`` and every
-        rung change is declared + logged;
+        deterministic jitter). Under an installed fault plan it
+        escalates through the ``repro.ft.degrade`` ladder (pallas→xla,
+        placement→single, reach reduced-hop); a downgraded batch's
+        queries are stamped ``degraded`` and every rung change is
+        declared + logged. Without one, retries rerun the requested
+        configuration only: a real failure is never answered from
+        another backend or placement;
       * a NaN/Inf guardrail probes each batch's host-side output and
         aborts a poisoned batch cleanly (retryable; terminal ``error``
         if the ladder runs dry);
@@ -436,6 +438,11 @@ def serve_mixed(g, queries, batch: int, backend: str, hops: int = 3,
                  # rungs we can realize here: the runner's own placement,
                  # or the in-process single-device fallback
                  if r.placement in (placement, "single")]
+        if plan is None:
+            # outside a chaos run a failure is a fault to surface, never a
+            # reason to answer from another backend or placement: retries
+            # rerun the requested configuration only
+            rungs = rungs[:1]
         run_default = lambda k, s, bk2, h: _run_kind(g, k, s, bk2, h,
                                                      budget)
         run_kind = runner if runner is not None else run_default
@@ -616,8 +623,9 @@ def serve_mixed(g, queries, batch: int, backend: str, hops: int = 3,
     total_s = time.monotonic() - t_start
 
     if validate:                         # oracles off the serving clock
-        for kind, sl, field in answers:
-            failures += _validate_kind(g, kind, sl, field, hops)
+        with obs.span("validate", category="validate"):
+            for kind, sl, field in answers:
+                failures += _validate_kind(g, kind, sl, field, hops)
     if metrics is not None:
         _count_totals(metrics, batches, overflow)
         metrics.counter("straggler_batches_total", len(wd.stragglers),
@@ -727,14 +735,16 @@ def main(argv=None):
     if plan is not None:
         log.warning(f"fault injection ACTIVE: {plan.spec!r} "
                     f"seed={plan.seed}")
-    # device health probe, once at startup: a failed device is named in
-    # the log (the eviction signal a multi-host controller would act on)
-    health = ft.check_devices()
-    for dev, ok in health.items():
-        if not ok:
-            log.warning(f"device {dev} failed the health probe — "
-                        f"evicting from the serving pool")
-    bk = B.resolve(args.backend)
+    try:
+        bk = B.resolve(args.backend)
+    except B.PallasUnavailableError as exc:
+        raise SystemExit(str(exc))
+    compile_cache.enable()
+    # device health probe, once at startup: a device that fails it stops
+    # the server before it takes a query
+    failed = [dev for dev, ok in ft.check_devices().items() if not ok]
+    if failed:
+        raise SystemExit(f"device health probe failed on {failed}")
     metrics = Metrics() if args.metrics else None
     with obs.span("build_graph", category="setup",
                   args={"kind": args.graph, "scale": args.scale}):
@@ -840,15 +850,17 @@ def main(argv=None):
                       args={"kinds": ",".join(kinds)}):
             for _ in range(args.warmup):        # one trace per kind
                 for k in kinds:
+                    srcs = rng.integers(0, g.num_vertices, args.batch)
+                    if plan is None:
+                        run_warm(k, srcs, bk, args.hops)
+                        continue
                     try:
-                        run_warm(k, rng.integers(0, g.num_vertices,
-                                                 args.batch),
-                                 bk, args.hops)
+                        run_warm(k, srcs, bk, args.hops)
                     except Exception as exc:
-                        # warmup is best-effort: under an installed
-                        # fault plan a cold trace can hit an injected
-                        # provider miss here; serving traces the kind on
-                        # first flush, inside the retry boundary
+                        # under an installed fault plan a cold trace can
+                        # hit an injected provider miss here; serving
+                        # traces the kind on first flush, inside the
+                        # retry boundary
                         log.warning(f"warmup {k} failed "
                                     f"({type(exc).__name__}: {exc}); "
                                     f"first flush will pay the trace")
@@ -895,9 +907,9 @@ def main(argv=None):
              f"p95 {stats.get('lat_ms_p95', 0)} "
              f"p99 {stats.get('lat_ms_p99', 0)}, n={stats['samples']})")
     counts = stats.get("status_counts")
-    if counts and any(counts[s] for s in STATUSES if s != "ok"):
+    if counts:
         log.info("statuses: " + " ".join(
-            f"{s}={counts[s]}" for s in STATUSES if counts[s]))
+            f"{s}={counts[s]}" for s in STATUSES))
     for k, row in stats.get("per_kind", {}).items():
         log.info(f"  {k:9s} {row['requests']:4d} queries  "
                  f"lat ms mean {row['lat_ms_mean']} "
@@ -931,6 +943,7 @@ def main(argv=None):
         rows.append(stats)
         with open(args.json, "w") as f:
             json.dump(rows, f, indent=1)
+    return stats
 
 
 if __name__ == "__main__":

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, x, mesh: Mesh,
@@ -44,7 +44,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, mesh: Mesh,
         shard_map, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     def run(params_s, xs_rep):
         my_params = jax.tree.map(lambda a: a[0], params_s)
         stage = jax.lax.axis_index(axis)

@@ -165,7 +165,7 @@ def test_sharded_storage_plan_parity():
         from repro.core.partition import partition_1d
         from repro.core.primitives import bfs, pagerank, sssp
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             g64 = G.rmat(7, 8, seed=5, weighted=True, index_dtype="int64")
             src = int(np.argmax(np.diff(np.asarray(g64.row_offsets))))
             labels = np.asarray(bfs(g64, src).labels)

@@ -139,7 +139,7 @@ def test_gather_cols_edgeless_store():
 
 
 def test_x64_build_keeps_plan_dtypes():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         g = G.rmat(6, 4, seed=1, weighted=True)
         assert g.plan.index_dtype == "int16"
         assert g.col_indices.dtype == np.int16
@@ -156,7 +156,7 @@ def test_int64_plan_requires_x64():
     e = np.zeros(0, np.int64)
     with pytest.raises(RuntimeError, match="jax_enable_x64"):
         G.from_edge_list(e, e, n=4, index_dtype="int64")
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         g = G.from_edge_list(e, e, n=4, index_dtype="int64")
         assert g.col_indices.dtype == np.int64
 
