@@ -93,7 +93,7 @@ _SKIP_FILE_RE = re.compile(r"#\s*reprolint:\s*skip-file")
 _TIMING_FNS = {"time.monotonic", "time.monotonic_ns", "time.time",
                "time.perf_counter", "time.perf_counter_ns"}
 _FENCE_ATTR = "block_until_ready"
-_FENCE_CALLS = {"timed", "span", "timed_span"}
+_FENCE_CALLS = {"timed", "span"}
 # calls whose function-valued arguments are traced by JAX
 _TRACING_WRAPPERS = {"jit", "vmap", "pmap", "while_loop", "fori_loop",
                      "scan", "cond", "switch", "map", "shard_map",

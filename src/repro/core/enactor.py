@@ -33,6 +33,14 @@ with a probe the loop returns the filled buffer as one extra element.
 Probes observe, never steer: nothing they compute feeds back into the
 step, which is what makes the telemetry on/off bit-parity contract
 (tests/test_obs.py) hold by construction.
+
+Scope names (DESIGN.md §10): every device op a primitive compiles
+carries an ``op_name`` under one of three roots — ``enactor.*`` for the
+loop machinery here (``enactor.loop``, ``enactor.select_lanes``,
+``enactor.tier``, ``enactor.telemetry``) and the primitives' direction
+choice (``enactor.direction``), ``op.*`` for operators and their apply,
+``primitive.*`` for set-up and the result. ``tiered_step`` tags each rung
+``tier_<cap>``. The scopes change HLO metadata only.
 """
 from __future__ import annotations
 
@@ -44,6 +52,7 @@ import jax.numpy as jnp
 S = TypeVar("S")
 
 
+@jax.named_scope("enactor.loop")
 def run_until(cond: Callable[[S], jax.Array],
               body: Callable[[S], S],
               state: S,
@@ -96,13 +105,16 @@ def run_until(cond: Callable[[S], jax.Array],
     def _body_t(carry):
         state, it, buf = carry
         new = body(state)
-        return new, it + 1, buf.record(**probe(state, new))
+        with jax.named_scope("enactor.telemetry"):
+            buf = buf.record(**probe(state, new))
+        return new, it + 1, buf
 
     final, iters, buf = jax.lax.while_loop(
         _cond_t, _body_t, (state, jnp.int32(0), telemetry))
     return final, iters, buf
 
 
+@jax.named_scope("enactor.select_lanes")
 def select_lanes(mask: jax.Array, on_true: S, on_false: S) -> S:
     """Per-lane pytree select: ``mask`` (B,) broadcast against every
     leaf's leading batch axis. The one place the batched engine's
@@ -116,6 +128,7 @@ def select_lanes(mask: jax.Array, on_true: S, on_false: S) -> S:
     return jax.tree_util.tree_map(pick, on_true, on_false)
 
 
+@jax.named_scope("enactor.loop")
 def run_until_any(cond: Callable[[S], jax.Array],
                   body: Callable[[S], S],
                   state: S,
@@ -177,7 +190,8 @@ def run_until_any(cond: Callable[[S], jax.Array],
     def _body_t(carry):
         st, lane_iters, it, active, buf = carry
         new = select_lanes(active, body(st), st)
-        buf = buf.record(**probe(st, new))
+        with jax.named_scope("enactor.telemetry"):
+            buf = buf.record(**probe(st, new))
         return (new, lane_iters + active.astype(jnp.int32), it + 1,
                 cond(new), buf)
 
@@ -205,17 +219,24 @@ def tiered_step(need, caps: Sequence[int],
     placement, sharded and 2d alike, where per-device tier choices
     would desynchronize collective shapes).
 
+    Each rung runs under a ``tier_<cap>`` scope and the rung choice under
+    ``enactor.tier``, so a trace attributes time per rung.
+
     ``with_index=True`` additionally returns the chosen tier index as a
     traced int32 — the telemetry hook for "which rung fired this step"
     without the caller recomputing the ladder search.
     """
+    def rung(cap):
+        return jax.named_scope(f"tier_{cap}")(step_of(cap))
+
     if len(caps) == 1:
         if with_index:
-            return step_of(caps[0])(state), jnp.int32(0)
-        return step_of(caps[0])(state)
+            return rung(caps[0])(state), jnp.int32(0)
+        return rung(caps[0])(state)
     from .frontier import tier_index
-    idx = tier_index(need, tuple(caps))
-    out = jax.lax.switch(idx, [step_of(c) for c in caps], state)
+    with jax.named_scope("enactor.tier"):
+        idx = tier_index(need, tuple(caps))
+        out = jax.lax.switch(idx, [rung(c) for c in caps], state)
     if with_index:
         return out, idx
     return out

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import storage as S
+from ..obs.tracing import span
 
 
 @jax.tree_util.register_pytree_node_class
@@ -218,11 +219,12 @@ class Graph:
         ell_w = ell_width_for(counts)
         over = _overflow_edges(ro, src, ell_w)
         if build_csc:
-            csc = _build_csc(n, src, ci.astype(np.int64), vals)
-            csc_ell = ell_width_for(np.diff(csc[0]))
-            csc_seg = np.repeat(np.arange(n, dtype=np.int32),
-                                np.diff(csc[0]))
-            csc_over = _overflow_edges(csc[0], csc_seg, csc_ell)
+            with span("graph.csc", category="setup"):
+                csc = _build_csc(n, src, ci.astype(np.int64), vals)
+                csc_ell = ell_width_for(np.diff(csc[0]))
+                csc_seg = np.repeat(np.arange(n, dtype=np.int32),
+                                    np.diff(csc[0]))
+                csc_over = _overflow_edges(csc[0], csc_seg, csc_ell)
 
         def _idx(a):
             """Pin a structural index array to the plan's dtype on
@@ -237,44 +239,50 @@ class Graph:
                     f"{out.dtype})")
             return out
 
-        col_enc = csc_enc = None
-        col_dense = _idx(ci)
-        csc_dense = _idx(csc[1]) if csc[1] is not None else None
-        if plan.encoding == "delta":
-            col_enc = S.encode_delta(ro, ci, src)
-            col_dense = None
-            if csc[1] is not None:
-                csc_enc = S.encode_delta(csc[0], csc[1], csc_seg)
-                csc_dense = None
-        # value_dtype="bf16" halves resident value bytes; compute
-        # promotes back through float32 (semiring.with_precision is the
-        # compute-side knob — the two compose but are independent)
-        vdt = jnp.bfloat16 if plan.value_dtype == "bf16" else jnp.float32
-        return cls(
-            row_offsets=jnp.asarray(ro.astype(np.int32)),
-            col_indices=col_dense,
-            edge_values=jnp.asarray(vals, vdt) if vals is not None else None,
-            csc_offsets=(jnp.asarray(csc[0].astype(np.int32))
-                         if csc[0] is not None else None),
-            csc_indices=csc_dense,
-            csc_edge_values=(jnp.asarray(csc[2], vdt)
-                             if csc[2] is not None else None),
-            csc_edge_ids=jnp.asarray(csc[3]) if csc[3] is not None else None,
-            row_seg=jnp.asarray(src),
-            csc_row_seg=(jnp.asarray(csc_seg)
-                         if csc_seg is not None else None),
-            over_pos=jnp.asarray(over[0]),
-            over_row=jnp.asarray(over[1]),
-            csc_over_pos=(jnp.asarray(csc_over[0])
-                          if csc_over[0] is not None else None),
-            csc_over_row=(jnp.asarray(csc_over[1])
-                          if csc_over[1] is not None else None),
-            col_enc=col_enc,
-            csc_enc=csc_enc,
-            ell_width=ell_w,
-            csc_ell_width=csc_ell,
-            plan=plan,
-        )
+        # host → device: the transfer is fenced, so the span times it
+        with span("graph.transfer", category="setup"):
+            col_enc = csc_enc = None
+            col_dense = _idx(ci)
+            csc_dense = _idx(csc[1]) if csc[1] is not None else None
+            if plan.encoding == "delta":
+                col_enc = S.encode_delta(ro, ci, src)
+                col_dense = None
+                if csc[1] is not None:
+                    csc_enc = S.encode_delta(csc[0], csc[1], csc_seg)
+                    csc_dense = None
+            # value_dtype="bf16" halves resident value bytes; compute
+            # promotes back through float32 (semiring.with_precision is the
+            # compute-side knob — the two compose but are independent)
+            vdt = jnp.bfloat16 if plan.value_dtype == "bf16" else jnp.float32
+            g = cls(
+                row_offsets=jnp.asarray(ro.astype(np.int32)),
+                col_indices=col_dense,
+                edge_values=(jnp.asarray(vals, vdt)
+                             if vals is not None else None),
+                csc_offsets=(jnp.asarray(csc[0].astype(np.int32))
+                             if csc[0] is not None else None),
+                csc_indices=csc_dense,
+                csc_edge_values=(jnp.asarray(csc[2], vdt)
+                                 if csc[2] is not None else None),
+                csc_edge_ids=(jnp.asarray(csc[3])
+                              if csc[3] is not None else None),
+                row_seg=jnp.asarray(src),
+                csc_row_seg=(jnp.asarray(csc_seg)
+                             if csc_seg is not None else None),
+                over_pos=jnp.asarray(over[0]),
+                over_row=jnp.asarray(over[1]),
+                csc_over_pos=(jnp.asarray(csc_over[0])
+                              if csc_over[0] is not None else None),
+                csc_over_row=(jnp.asarray(csc_over[1])
+                              if csc_over[1] is not None else None),
+                col_enc=col_enc,
+                csc_enc=csc_enc,
+                ell_width=ell_w,
+                csc_ell_width=csc_ell,
+                plan=plan,
+            )
+            jax.block_until_ready(g)
+        return g
 
 
 class GraphValidationError(ValueError):
@@ -424,53 +432,63 @@ def from_edge_list(src, dst, n: Optional[int] = None, values=None,
     """Build a Graph from host-side edge arrays.
 
     Mirrors the paper's dataset preparation: optionally symmetrize,
-    remove self loops and duplicate edges (paper Table 4 note).
+    remove self loops and duplicate edges (paper Table 4 note). Spans:
+    ``graph.build`` around ``graph.symmetrize`` (with self-loop removal
+    and deduplication), ``graph.csr``, and ``from_csr``'s ``graph.csc``
+    and ``graph.transfer``.
     """
-    src = np.asarray(src, dtype=np.int64)
-    dst = np.asarray(dst, dtype=np.int64)
-    if values is not None:
-        values = np.asarray(values, dtype=np.float32)
-    if n is None:
-        n = int(max(src.max(initial=-1), dst.max(initial=-1))) + 1 if len(src) else 0
-    if undirected:
-        src, dst = np.concatenate([src, dst]), np.concatenate([dst, src])
+    with span("graph.build", category="setup"):
+        src = np.asarray(src, dtype=np.int64)
+        dst = np.asarray(dst, dtype=np.int64)
         if values is not None:
-            values = np.concatenate([values, values])
-    if remove_self_loops and len(src):
-        keep = src != dst
-        src, dst = src[keep], dst[keep]
-        if values is not None:
-            values = values[keep]
-    if deduplicate and len(src):
-        key = src * n + dst
-        _, first = np.unique(key, return_index=True)
-        first.sort()
-        src, dst = src[first], dst[first]
-        if values is not None:
-            values = values[first]
-    # CSR: sort by (src, dst) so neighbor lists are sorted (needed by
-    # segmented intersection; paper §4.3 assumes sorted adjacency lists).
-    if sort_neighbors and len(src):
-        order = np.lexsort((dst, src))
-    else:
-        order = np.argsort(src, kind="stable")
-    src, dst = src[order], dst[order]
-    if values is not None:
-        values = values[order]
-    counts = np.bincount(src, minlength=n)
-    row_offsets = np.zeros(n + 1, dtype=np.int32)
-    np.cumsum(counts, out=row_offsets[1:])
-    # Graph.from_csr is the single build-time home of kernel metadata
-    # (CSC mirror + ELL pack widths) — computed once, never under jit.
-    # Rows are already in the order this function's flags chose, so the
-    # constructor must not re-sort them. ``encoding="delta"`` needs
-    # sorted rows (storage.encode_delta validates).
-    if encoding == "delta" and not sort_neighbors:
-        raise ValueError("encoding='delta' requires sort_neighbors=True")
-    return Graph.from_csr(row_offsets, dst, values,
-                          build_csc=build_csc, sort_neighbors=False,
-                          index_dtype=index_dtype, encoding=encoding,
-                          value_dtype=value_dtype)
+            values = np.asarray(values, dtype=np.float32)
+        if n is None:
+            n = (int(max(src.max(initial=-1), dst.max(initial=-1))) + 1
+                 if len(src) else 0)
+        with span("graph.symmetrize", category="setup"):
+            if undirected:
+                src, dst = (np.concatenate([src, dst]),
+                            np.concatenate([dst, src]))
+                if values is not None:
+                    values = np.concatenate([values, values])
+            if remove_self_loops and len(src):
+                keep = src != dst
+                src, dst = src[keep], dst[keep]
+                if values is not None:
+                    values = values[keep]
+            if deduplicate and len(src):
+                key = src * n + dst
+                _, first = np.unique(key, return_index=True)
+                first.sort()
+                src, dst = src[first], dst[first]
+                if values is not None:
+                    values = values[first]
+        with span("graph.csr", category="setup"):
+            # CSR: sort by (src, dst) so neighbor lists are sorted (needed
+            # by segmented intersection; paper §4.3 assumes sorted
+            # adjacency lists).
+            if sort_neighbors and len(src):
+                order = np.lexsort((dst, src))
+            else:
+                order = np.argsort(src, kind="stable")
+            src, dst = src[order], dst[order]
+            if values is not None:
+                values = values[order]
+            counts = np.bincount(src, minlength=n)
+            row_offsets = np.zeros(n + 1, dtype=np.int32)
+            np.cumsum(counts, out=row_offsets[1:])
+        # Graph.from_csr is the single build-time home of kernel
+        # metadata (CSC mirror + ELL pack widths) — computed once, never
+        # under jit. Rows are already in the order this function's flags
+        # chose, so the constructor must not re-sort them.
+        # ``encoding="delta"`` needs sorted rows (storage.encode_delta
+        # validates).
+        if encoding == "delta" and not sort_neighbors:
+            raise ValueError("encoding='delta' requires sort_neighbors=True")
+        return Graph.from_csr(row_offsets, dst, values,
+                              build_csc=build_csc, sort_neighbors=False,
+                              index_dtype=index_dtype, encoding=encoding,
+                              value_dtype=value_dtype)
 
 
 def edge_list(graph: Graph) -> tuple[np.ndarray, np.ndarray]:

@@ -56,6 +56,10 @@ Batched operators:
   Functors keep their single-lane signature and are vmapped over the
   batch axis, so BFS/SSSP share one functor between the single- and
   multi-source paths; problem-data pytrees carry a leading batch axis.
+
+Every public operator runs under an ``op.<name>`` ``jax.named_scope``
+(``op.advance``, ``op.advance_filter``, ``op.pull``, ``op.filter``, ...),
+so a profiler trace names its device ops (DESIGN.md §10).
 """
 from __future__ import annotations
 
@@ -169,6 +173,7 @@ def _frontier_base_vertices(graph: Graph, frontier: SparseFrontier,
     raise ValueError(f"unknown input_kind {input_kind}")
 
 
+@jax.named_scope("op.advance")
 def advance(graph: Graph, frontier: SparseFrontier, cap_out: int,
             functor: Optional[Callable] = None, data=None,
             input_kind: str = "vertex", strategy: str = "LB", *,
@@ -261,6 +266,7 @@ def _advance_batch_xla(row_offsets: jax.Array, col_indices: S.ColStore,
     )(base, sizes)
 
 
+@jax.named_scope("op.advance")
 def advance_batch(graph: Graph, frontier: BatchedSparseFrontier,
                   cap_out: int, functor: Optional[Callable] = None,
                   data=None, input_kind: str = "vertex",
@@ -383,6 +389,7 @@ def _advance_filter_batch_xla(row_offsets: jax.Array,
     )(base, sizes, visited)
 
 
+@jax.named_scope("op.advance_filter")
 def advance_filter(graph: Graph, frontier: SparseFrontier,
                    visited: jax.Array, cap_out: int,
                    cap_front: Optional[int] = None, *,
@@ -417,6 +424,7 @@ def advance_filter(graph: Graph, frontier: SparseFrontier,
     return SparseFrontier(ids=ids, length=length), srcs, total
 
 
+@jax.named_scope("op.advance_filter")
 def advance_filter_batch(graph: Graph, frontier: BatchedSparseFrontier,
                          visited: jax.Array, cap_out: int,
                          cap_front: Optional[int] = None, *,
@@ -471,6 +479,7 @@ def advance_to_vertex_frontier_batch(res: AdvanceResult,
     return BatchedSparseFrontier(ids=buf, lengths=lengths)
 
 
+@jax.named_scope("op.pull")
 def advance_pull(graph: Graph, unvisited: DenseFrontier,
                  current: DenseFrontier, return_preds: bool = False):
     """Pull-based advance (paper §5.1.4, Fig. 13).
@@ -507,6 +516,7 @@ def advance_pull(graph: Graph, unvisited: DenseFrontier,
     return DenseFrontier(new_flags), preds
 
 
+@jax.named_scope("op.pull")
 def advance_pull_batch(graph: Graph, unvisited: BatchedDenseFrontier,
                        current: BatchedDenseFrontier,
                        return_preds: bool = False):
@@ -556,6 +566,7 @@ def _uniquify_hash(ids: jax.Array, keep: jax.Array,
     return keep & ~dup
 
 
+@jax.named_scope("op.filter")
 def filter_frontier(frontier: SparseFrontier,
                     functor: Optional[Callable] = None, data=None,
                     n: Optional[int] = None, uniquify: str = "none",
@@ -589,6 +600,7 @@ def filter_frontier(frontier: SparseFrontier,
     return SparseFrontier(ids=buf, length=length), data
 
 
+@jax.named_scope("op.filter")
 def filter_frontier_batch(frontier: BatchedSparseFrontier,
                           functor: Optional[Callable] = None, data=None,
                           n: Optional[int] = None, uniquify: str = "none",
@@ -649,6 +661,7 @@ def partition_frontier(frontier: SparseFrontier, predicate: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("op.neighborhood_reduce")
 def neighborhood_reduce(graph: Graph, frontier: SparseFrontier, cap_out: int,
                         edge_map: Callable, reduce_op: str = "add",
                         init=None, data=None, strategy: str = "LB",
@@ -722,6 +735,7 @@ def _segment_search_xla(haystack: jax.Array, lo: jax.Array, hi: jax.Array,
     return _searchsorted_segment(haystack, lo, hi, needles)
 
 
+@jax.named_scope("op.intersect")
 def segmented_intersect(graph: Graph, fa: SparseFrontier, fb: SparseFrontier,
                         cap_out: int, *, backend: Optional[str] = None,
                         use_kernel: Optional[bool] = None

@@ -103,19 +103,20 @@ def _bfs_impl(graph: Graph, srcs: jax.Array, do_a: float, do_b: float,
               (max(cap_e, 1),))
     params = DirectionParams(do_a=do_a, do_b=do_b, enabled=direction)
 
-    lane = jnp.arange(b)
-    labels = jnp.full((b, n), -1, jnp.int32).at[lane, srcs].set(0)
-    preds = jnp.full((b, n), -1, jnp.int32)
-    visited = jnp.zeros((b, n), bool).at[lane, srcs].set(True)
-    frontier = from_ids_batch(srcs, cap_v)
-    state = BFSState(labels=labels, preds=preds, frontier=frontier,
-                     dense=visited, visited=visited,
-                     n_f=jnp.ones((b,), jnp.int32),
-                     n_u=jnp.full((b,), n - 1, jnp.int32),
-                     depth=jnp.zeros((b,), jnp.int32),
-                     mode=jnp.full((b,), PUSH),
-                     pull_iters=jnp.zeros((b,), jnp.int32),
-                     overflow=jnp.zeros((b,), jnp.int32))
+    with jax.named_scope("primitive.init"):
+        lane = jnp.arange(b)
+        labels = jnp.full((b, n), -1, jnp.int32).at[lane, srcs].set(0)
+        preds = jnp.full((b, n), -1, jnp.int32)
+        visited = jnp.zeros((b, n), bool).at[lane, srcs].set(True)
+        frontier = from_ids_batch(srcs, cap_v)
+        state = BFSState(labels=labels, preds=preds, frontier=frontier,
+                         dense=visited, visited=visited,
+                         n_f=jnp.ones((b,), jnp.int32),
+                         n_u=jnp.full((b,), n - 1, jnp.int32),
+                         depth=jnp.zeros((b,), jnp.int32),
+                         mode=jnp.full((b,), PUSH),
+                         pull_iters=jnp.zeros((b,), jnp.int32),
+                         overflow=jnp.zeros((b,), jnp.int32))
 
     def fused_push_at(cap_t: int):
         """LB push at one capacity tier: the fused advance_filter does
@@ -129,38 +130,41 @@ def _bfs_impl(graph: Graph, srcs: jax.Array, do_a: float, do_b: float,
             new_frontier, srcs, totals = ops.advance_filter_batch(
                 graph, st.frontier, st.visited, cap_t, cap_front=cap_v,
                 backend=backend)
-            ids = new_frontier.ids
-            tgt = jnp.where(ids >= 0, ids, n)    # n = out of bounds → drop
-            # apply: set depth (one surviving slot per discovery, so the
-            # scatters are conflict-free; paper §5.2.1)
-            labels = jax.vmap(
-                lambda l, t, d1: l.at[t].set(d1, mode="drop"))(
-                    st.labels, tgt, depth1)
-            if record_preds:
-                preds = jax.vmap(
-                    lambda p, t, s: p.at[t].set(s, mode="drop"))(
-                        st.preds, tgt, srcs)
-            else:
-                preds = st.preds
-            visited = jax.vmap(
-                lambda v, t: v.at[t].set(True, mode="drop"))(
-                    st.visited, tgt)
-            # exact culling can never exceed the min(n, m) vertex
-            # frontier; the counter stays for the state contract
-            ovf = jnp.maximum(totals - new_frontier.lengths, 0)
-            return st._replace(labels=labels, preds=preds,
-                               frontier=new_frontier, dense=visited,
-                               visited=visited,
-                               n_f=new_frontier.lengths,
-                               n_u=st.n_u - new_frontier.lengths,
-                               depth=depth1, overflow=st.overflow + ovf)
+            with jax.named_scope("op.apply"):
+                ids = new_frontier.ids
+                tgt = jnp.where(ids >= 0, ids, n)    # n = out of bounds
+                # apply: set depth (one surviving slot per discovery, so
+                # the scatters are conflict-free; paper §5.2.1)
+                labels = jax.vmap(
+                    lambda l, t, d1: l.at[t].set(d1, mode="drop"))(
+                        st.labels, tgt, depth1)
+                if record_preds:
+                    preds = jax.vmap(
+                        lambda p, t, s: p.at[t].set(s, mode="drop"))(
+                            st.preds, tgt, srcs)
+                else:
+                    preds = st.preds
+                visited = jax.vmap(
+                    lambda v, t: v.at[t].set(True, mode="drop"))(
+                        st.visited, tgt)
+                # exact culling can never exceed the min(n, m) vertex
+                # frontier; the counter stays for the state contract
+                ovf = jnp.maximum(totals - new_frontier.lengths, 0)
+                return st._replace(labels=labels, preds=preds,
+                                   frontier=new_frontier, dense=visited,
+                                   visited=visited,
+                                   n_f=new_frontier.lengths,
+                                   n_u=st.n_u - new_frontier.lengths,
+                                   depth=depth1,
+                                   overflow=st.overflow + ovf)
 
         return push_step
 
     def legacy_push_step(st: BFSState):
         # TWC/THREAD ablation path: unfused advance → filter with the
         # idempotence-selected uniquify, at full capacity
-        depth1 = st.depth + 1
+        with jax.named_scope("op.apply"):
+            depth1 = st.depth + 1
 
         def functor(s, d, e, rank, valid, data):
             # cond functor: discover unvisited destinations (single-lane
@@ -172,55 +176,69 @@ def _bfs_impl(graph: Graph, srcs: jax.Array, do_a: float, do_b: float,
                                    functor=functor,
                                    data={"visited": st.visited},
                                    strategy=strategy, backend=backend)
-        # apply: set depth (idempotent write — same value for all dups,
-        # so no atomics are needed; paper §5.2.1)
-        tgt = jnp.where(res.valid, res.dst, n)   # n = out of bounds → drop
-        labels = jax.vmap(lambda l, t, d1: l.at[t].set(d1, mode="drop"))(
-            st.labels, tgt, depth1)
-        if record_preds:
-            preds = jax.vmap(lambda p, t, s: p.at[t].set(s, mode="drop"))(
-                st.preds, tgt, res.src)
-        else:
-            preds = st.preds
-        visited = jax.vmap(ops.scatter_or)(res.dst, res.valid, st.visited)
-        # contract: compact the full expansion, then uniquify down into
-        # the cap_v vertex frontier (exact unless idempotent mode;
-        # idempotent mode uses the cheap hash-culling heuristic, whose
-        # leftover duplicates are the only way to overflow cap_v)
-        wide = ops.advance_to_vertex_frontier_batch(res, cap_e,
-                                                    backend=backend)
-        uniq = "hash" if idempotence else "exact"
-        new_frontier, _, ovf = ops.filter_frontier_batch(
-            wide, n=n, uniquify=uniq, cap=cap_v, backend=backend)
-        return st._replace(labels=labels, preds=preds,
-                           frontier=new_frontier, dense=visited,
-                           visited=visited, n_f=new_frontier.lengths,
-                           n_u=st.n_u - new_frontier.lengths, depth=depth1,
-                           overflow=st.overflow + ovf)
+        with jax.named_scope("op.apply"):
+            # apply: set depth (idempotent write — same value for all
+            # dups, so no atomics are needed; paper §5.2.1)
+            tgt = jnp.where(res.valid, res.dst, n)   # n = out of bounds
+            labels = jax.vmap(
+                lambda l, t, d1: l.at[t].set(d1, mode="drop"))(
+                    st.labels, tgt, depth1)
+            if record_preds:
+                preds = jax.vmap(
+                    lambda p, t, s: p.at[t].set(s, mode="drop"))(
+                        st.preds, tgt, res.src)
+            else:
+                preds = st.preds
+            visited = jax.vmap(ops.scatter_or)(res.dst, res.valid,
+                                               st.visited)
+        with jax.named_scope("op.filter"):
+            # contract: compact the full expansion, then uniquify down
+            # into the cap_v vertex frontier (exact unless idempotent
+            # mode; idempotent mode uses the cheap hash-culling
+            # heuristic, whose leftover duplicates are the only way to
+            # overflow cap_v)
+            wide = ops.advance_to_vertex_frontier_batch(res, cap_e,
+                                                        backend=backend)
+            uniq = "hash" if idempotence else "exact"
+            new_frontier, _, ovf = ops.filter_frontier_batch(
+                wide, n=n, uniquify=uniq, cap=cap_v, backend=backend)
+        with jax.named_scope("op.apply"):
+            return st._replace(labels=labels, preds=preds,
+                               frontier=new_frontier, dense=visited,
+                               visited=visited, n_f=new_frontier.lengths,
+                               n_u=st.n_u - new_frontier.lengths,
+                               depth=depth1, overflow=st.overflow + ovf)
 
     def push_step(st: BFSState):
         if strategy != "LB":
             return legacy_push_step(st)
-        need = jnp.max(ops.frontier_workload(graph, st.frontier))
+        with jax.named_scope("enactor.tier"):
+            need = jnp.max(ops.frontier_workload(graph, st.frontier))
         return tiered_step(need, caps_e, fused_push_at, st)
 
     def pull_step(st: BFSState):
-        depth1 = st.depth + 1
+        with jax.named_scope("op.apply"):
+            depth1 = st.depth + 1
         current = BatchedDenseFrontier(st.dense)
-        unvisited = BatchedDenseFrontier(~st.visited)
+        with jax.named_scope("op.pull"):
+            unvisited = BatchedDenseFrontier(~st.visited)
         new_dense, pull_preds = ops.advance_pull_batch(
             graph, unvisited, current, return_preds=True)
-        labels = jnp.where(new_dense.flags, depth1[:, None], st.labels)
-        preds = (jnp.where(new_dense.flags, pull_preds, st.preds)
-                 if record_preds else st.preds)
-        visited = st.visited | new_dense.flags
-        n_new = new_dense.lengths
-        sparse = new_dense.to_sparse(cap_v, backend=backend)
-        return st._replace(labels=labels, preds=preds, frontier=sparse,
-                           dense=new_dense.flags, visited=visited,
-                           n_f=n_new, n_u=st.n_u - n_new, depth=depth1,
-                           pull_iters=st.pull_iters + 1)
+        with jax.named_scope("op.apply"):
+            labels = jnp.where(new_dense.flags, depth1[:, None], st.labels)
+            preds = (jnp.where(new_dense.flags, pull_preds, st.preds)
+                     if record_preds else st.preds)
+            visited = st.visited | new_dense.flags
+        with jax.named_scope("enactor.direction"):
+            n_new = new_dense.lengths
+            sparse = new_dense.to_sparse(cap_v, backend=backend)
+        with jax.named_scope("op.apply"):
+            return st._replace(labels=labels, preds=preds, frontier=sparse,
+                               dense=new_dense.flags, visited=visited,
+                               n_f=n_new, n_u=st.n_u - n_new, depth=depth1,
+                               pull_iters=st.pull_iters + 1)
 
+    @jax.named_scope("enactor.direction")
     def body(st: BFSState):
         if not direction:
             return push_step(st)
@@ -237,6 +255,7 @@ def _bfs_impl(graph: Graph, srcs: jax.Array, do_a: float, do_b: float,
             # idle direction costs nothing
             return jax.lax.cond(mode[0] == PULL, pull_step, push_step, st)
 
+        @jax.named_scope("mixed")
         def mixed_step(st):
             # lanes disagree: compute both directions in lockstep and
             # select per lane
@@ -285,13 +304,15 @@ def _bfs_impl(graph: Graph, srcs: jax.Array, do_a: float, do_b: float,
     else:
         final, lane_iters, _ = run_until_any(lambda st: st.n_f > 0, body,
                                              state, max_iter=mi)
-    edges = jnp.sum(jnp.where(final.labels >= 0,
-                              graph.degrees[None, :], 0),
-                    axis=1).astype(jnp.int32)
-    result = BFSResult(labels=final.labels, preds=final.preds,
-                       iterations=lane_iters, pull_iters=final.pull_iters,
-                       edges_visited=edges, overflow=final.overflow,
-                       converged=final.n_f == 0)
+    with jax.named_scope("primitive.result"):
+        edges = jnp.sum(jnp.where(final.labels >= 0,
+                                  graph.degrees[None, :], 0),
+                        axis=1).astype(jnp.int32)
+        result = BFSResult(labels=final.labels, preds=final.preds,
+                           iterations=lane_iters,
+                           pull_iters=final.pull_iters,
+                           edges_visited=edges, overflow=final.overflow,
+                           converged=final.n_f == 0)
     return (result, buf) if telemetry else result
 
 

@@ -100,36 +100,40 @@ def _pagerank_impl(graph: Graph, inv_deg: jax.Array, damping: jax.Array,
         # single-device ranks. A single IEEE multiply has no such
         # freedom, so placement bit-parity (a tested contract) holds.
         # inv_deg is 0 on dangling vertices, folding the deg>0 guard in.
-        contrib = st.rank * inv_deg
-        # acc = Aᵀ ⊗ contrib over plus-times (structural adjacency). The
-        # CSC edge→row map rides along as build-time metadata so the
-        # sweep never re-derives it inside the loop (it was the largest
-        # single per-iteration cost of this impl).
-        acc = spmv_op(graph.csc_offsets, csc, None, contrib,
-                      sr, ell_width, None, graph.csc_row_seg,
-                      graph.csc_over_pos, graph.csc_over_row)
-        # grouping-fixed sum — see _fixed_tree_sum for why jnp.sum would
-        # break placement bit-parity here
-        dangling = _fixed_tree_sum(
-            jnp.where(inv_deg == 0, st.rank, 0.0)) / n
-        new_rank = (1.0 - damping) / n + damping * (acc + dangling)
-        # convergence filter: retire vertices whose rank has settled
-        still = jnp.abs(new_rank - st.rank) > tol
-        return PRState(rank=new_rank, active=still,
-                       n_active=jnp.sum(still).astype(jnp.int32),
-                       iters=st.iters + 1)
+        with jax.named_scope("op.spmv"):
+            contrib = st.rank * inv_deg
+            # acc = Aᵀ ⊗ contrib over plus-times (structural adjacency).
+            # The CSC edge→row map rides along as build-time metadata so
+            # the sweep never re-derives it inside the loop (it was the
+            # largest single per-iteration cost of this impl).
+            acc = spmv_op(graph.csc_offsets, csc, None, contrib,
+                          sr, ell_width, None, graph.csc_row_seg,
+                          graph.csc_over_pos, graph.csc_over_row)
+        with jax.named_scope("op.apply"):
+            # grouping-fixed sum — see _fixed_tree_sum for why jnp.sum
+            # would break placement bit-parity here
+            dangling = _fixed_tree_sum(
+                jnp.where(inv_deg == 0, st.rank, 0.0)) / n
+            new_rank = (1.0 - damping) / n + damping * (acc + dangling)
+            # convergence filter: retire vertices whose rank has settled
+            still = jnp.abs(new_rank - st.rank) > tol
+            return PRState(rank=new_rank, active=still,
+                           n_active=jnp.sum(still).astype(jnp.int32),
+                           iters=st.iters + 1)
 
     # float32-pinned: under jax_enable_x64 the bare python literal would
     # seed a float64 rank vector and the whole loop would run (and
     # retrace) in double precision
-    state = PRState(rank=jnp.full((n,), 1.0 / n, jnp.float32),
-                    active=jnp.ones((n,), bool),
-                    n_active=jnp.int32(n), iters=jnp.int32(0))
+    with jax.named_scope("primitive.init"):
+        state = PRState(rank=jnp.full((n,), 1.0 / n, jnp.float32),
+                        active=jnp.ones((n,), bool),
+                        n_active=jnp.int32(n), iters=jnp.int32(0))
     # the caller's *requested* sweep count: "converged" means ranks
     # settled OR the requested sweeps all ran — only a budget cutting
     # max_iter below full_iter can make it False
     fi = max_iter if full_iter is None else full_iter
 
+    @jax.named_scope("primitive.result")
     def _conv(final, iters):
         return (final.n_active == 0) | (iters >= fi)
 

@@ -48,23 +48,29 @@ def _reach_impl(graph: Graph, srcs: jax.Array, k: int, backend: str,
     spmm_op = B.dispatch("spmm", backend, placement)
     csc = B.storage_arg("spmm", backend, placement, graph=graph,
                         side="csc")
-    r0 = jnp.zeros((n, b), jnp.float32).at[
-        srcs, jnp.arange(b, dtype=jnp.int32)].set(1.0)
+    with jax.named_scope("primitive.init"):
+        r0 = jnp.zeros((n, b), jnp.float32).at[
+            srcs, jnp.arange(b, dtype=jnp.int32)].set(1.0)
 
     def hop(_, r):
-        # complemented mask: rows already reached by EVERY lane cannot
-        # change (R is monotone under ⋁), so skip their sweep entirely
-        need = jnp.min(r, axis=1) < 1.0
-        new = spmm_op(graph.csc_offsets, csc, None, r,
-                      SR.or_and, ell_width, need, graph.csc_row_seg)
-        return jnp.maximum(r, new)
+        with jax.named_scope("op.spmm"):
+            # complemented mask: rows already reached by EVERY lane
+            # cannot change (R is monotone under ⋁), so skip their sweep
+            need = jnp.min(r, axis=1) < 1.0
+            new = spmm_op(graph.csc_offsets, csc, None, r,
+                          SR.or_and, ell_width, need, graph.csc_row_seg)
+        with jax.named_scope("op.apply"):
+            return jnp.maximum(r, new)
 
-    r = jax.lax.fori_loop(0, k, hop, r0)
-    reached = r.T > 0
-    return ReachResult(reached=reached,
-                       counts=jnp.sum(reached, axis=1).astype(jnp.int32),
-                       hops=jnp.int32(k),
-                       converged=jnp.bool_(True))
+    with jax.named_scope("enactor.loop"):
+        r = jax.lax.fori_loop(0, k, hop, r0)
+    with jax.named_scope("primitive.result"):
+        reached = r.T > 0
+        return ReachResult(reached=reached,
+                           counts=jnp.sum(reached, axis=1).astype(
+                               jnp.int32),
+                           hops=jnp.int32(k),
+                           converged=jnp.bool_(True))
 
 
 def reach_batch(graph, srcs, k: int = 3, *,

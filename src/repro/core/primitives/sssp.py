@@ -79,15 +79,16 @@ def _sssp_impl(graph: Graph, srcs: jax.Array, delta: jax.Array,
     caps_e = (B.tier_plan("advance", m)
               if (tiered and m > 0 and strategy != "THREAD")
               else (max(m, 1),))
-    lane = jnp.arange(b)
-    dist = jnp.full((b, n), INF).at[lane, srcs].set(0.0)
-    preds = jnp.full((b, n), -1, jnp.int32)
-    near = jnp.zeros((b, n), bool).at[lane, srcs].set(True)
-    state = SSSPState(dist=dist, preds=preds, near=near,
-                      far=jnp.zeros((b, n), bool),
-                      bucket=jnp.zeros((b,), jnp.int32),
-                      n_near=jnp.ones((b,), jnp.int32),
-                      relaxations=jnp.zeros((b,), jnp.int32))
+    with jax.named_scope("primitive.init"):
+        lane = jnp.arange(b)
+        dist = jnp.full((b, n), INF).at[lane, srcs].set(0.0)
+        preds = jnp.full((b, n), -1, jnp.int32)
+        near = jnp.zeros((b, n), bool).at[lane, srcs].set(True)
+        state = SSSPState(dist=dist, preds=preds, near=near,
+                          far=jnp.zeros((b, n), bool),
+                          bucket=jnp.zeros((b,), jnp.int32),
+                          n_near=jnp.ones((b,), jnp.int32),
+                          relaxations=jnp.zeros((b,), jnp.int32))
 
     def relax_at(cap_t: int):
         def relax_step(st: SSSPState):
@@ -95,10 +96,12 @@ def _sssp_impl(graph: Graph, srcs: jax.Array, delta: jax.Array,
         return relax_step
 
     def relax_step(st: SSSPState):
-        need = jnp.max(jnp.sum(
-            jnp.where(st.near, graph.degrees[None, :], 0), axis=1))
+        with jax.named_scope("enactor.tier"):
+            need = jnp.max(jnp.sum(
+                jnp.where(st.near, graph.degrees[None, :], 0), axis=1))
         return tiered_step(need, caps_e, relax_at, st)
 
+    @jax.named_scope("op.relax")
     def _relax_step(st: SSSPState, cap_t: int):
         frontier = BatchedDenseFrontier(st.near).to_sparse(
             n, backend=backend)
@@ -113,16 +116,18 @@ def _sssp_impl(graph: Graph, srcs: jax.Array, delta: jax.Array,
         safe_src = jnp.where(res.valid, res.src, 0)
         cand = jnp.take_along_axis(st.dist, safe_src, axis=1) + w
         # atomicMin replacement: segment-min into dist (paper Update_Label)
-        new_dist = jax.vmap(ops.scatter_min)(cand, res.dst, res.valid,
-                                             st.dist)
+        with jax.named_scope("op.apply"):
+            new_dist = jax.vmap(ops.scatter_min)(cand, res.dst, res.valid,
+                                                 st.dist)
         improved = new_dist < st.dist
         # Set_Pred: the winning edge writes the predecessor
         safe_dst = jnp.where(res.valid, res.dst, 0)
         winner = res.valid & (cand <= jnp.take_along_axis(new_dist,
                                                           safe_dst, axis=1))
-        preds = jax.vmap(lambda p, wn, d, s: p.at[
-            jnp.where(wn, d, n)].set(s, mode="drop"))(
-                st.preds, winner, res.dst, res.src)
+        with jax.named_scope("op.apply"):
+            preds = jax.vmap(lambda p, wn, d, s: p.at[
+                jnp.where(wn, d, n)].set(s, mode="drop"))(
+                    st.preds, winner, res.dst, res.src)
         # priority-queue split (near/far) on the improved vertices
         thresh = (st.bucket.astype(jnp.float32) + 1.0) * delta
         if use_delta:
@@ -141,6 +146,7 @@ def _sssp_impl(graph: Graph, srcs: jax.Array, delta: jax.Array,
                                           dtype=jnp.int32),
                            relaxations=relax)
 
+    @jax.named_scope("op.bucket")
     def pop_far(st: SSSPState):
         # near pile empty: advance the bucket to the smallest far distance
         far_min = jnp.min(jnp.where(st.far, st.dist, INF), axis=1)
@@ -202,10 +208,11 @@ def _sssp_impl(graph: Graph, srcs: jax.Array, delta: jax.Array,
     else:
         final, lane_iters, _ = run_until_any(cond, body, state,
                                              max_iter=mi)
-    result = SSSPResult(dist=final.dist, preds=final.preds,
-                        iterations=lane_iters,
-                        relaxations=final.relaxations,
-                        converged=~cond(final))
+    with jax.named_scope("primitive.result"):
+        result = SSSPResult(dist=final.dist, preds=final.preds,
+                            iterations=lane_iters,
+                            relaxations=final.relaxations,
+                            converged=~cond(final))
     return (result, buf) if telemetry else result
 
 

@@ -246,14 +246,23 @@ def main(argv=None):
                     help="write phase spans as Chrome trace-event JSON "
                          "(open at ui.perfetto.dev)")
     args = ap.parse_args(argv)
+    if not args.trace:
+        return _main(args)
+    with obs.capture():
+        try:
+            return _main(args)
+        finally:
+            n_ev = obs.export_chrome_trace(args.trace)
+            log.info(f"wrote {n_ev} trace events to {args.trace}")
+
+
+def _main(args):
     try:
         bk = B.resolve(args.backend)
     except B.PallasUnavailableError as exc:
         raise SystemExit(str(exc))
     compile_cache.enable()
 
-    if args.trace:
-        obs.reset()
     with obs.span("build_graph", category="setup",
                   args={"kind": args.graph, "scale": args.scale}):
         g = make_graph(args.graph, args.scale, args.edge_factor,
@@ -302,9 +311,6 @@ def main(argv=None):
                          + (" (lane 0)" if sources else "") + ":")
                 # reprolint: disable=RL005 -- multi-line table artifact; stdout is the CLI contract
                 print(trace.format_table(prefix="  "))
-    if args.trace:
-        n_ev = obs.export_chrome_trace(args.trace)
-        log.info(f"wrote {n_ev} trace events to {args.trace}")
     if failures:
         raise SystemExit(f"{failures} primitives failed validation")
     return results

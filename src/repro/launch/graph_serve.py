@@ -52,6 +52,7 @@ n-proportional. Results bit-match either way.
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import time
 
@@ -187,6 +188,10 @@ def _count_totals(m: Metrics, batches: int, overflow: int) -> None:
               help="BFS discoveries dropped by capped frontiers")
     m.counter("cache_hits_total", 0, help="answer-cache hits")
     m.counter("cache_misses_total", 0, help="answer-cache misses")
+
+
+# one id per serve_mixed call: its serve.flush spans carry it as "mixed"
+_MIXED_IDS = itertools.count()
 
 
 def _run_kind(g, kind: str, srcs: np.ndarray, backend: str, hops: int,
@@ -378,6 +383,12 @@ def serve_mixed(g, queries, batch: int, backend: str, hops: int = 3,
         if the ladder runs dry);
       * a :class:`repro.ft.StepWatchdog` times every flush — the
         robust-median straggler multiple lands in ``--metrics``.
+
+    Program spans (``obs.span``, on the profiler's clock): one
+    ``serve.mixed`` per call (``id``), a ``serve.flush`` per batch
+    (``kind``, live ``lanes``, ``attempts``, ``mixed`` = that id), and
+    inside each attempt ``serve.dispatch`` (the runner call),
+    ``serve.host_copy`` and ``serve.guardrail``.
     """
     n_q = len(queries)
     if n_q == 0:
@@ -408,6 +419,7 @@ def serve_mixed(g, queries, batch: int, backend: str, hops: int = 3,
                         help="queries whose batch needed >=1 retry")
     # reprolint: disable=RL004 -- run_kind fences internally (block_until_ready before return)
     t_start = time.monotonic()
+    mixed_id = next(_MIXED_IDS)
 
     def finish(qid, kind, src, status, t_enq, t_done=None, reason=None,
                attempts=1, degraded_to=None):
@@ -464,19 +476,23 @@ def serve_mixed(g, queries, batch: int, backend: str, hops: int = 3,
                 raise inject.ShardLossError(
                     f"injected shard loss during {kind} flush")
             h = rung.hops if rung.hops is not None else hops
-            if rung.placement != placement:
-                out = run_default(kind, srcs, rung.backend, h)
-            else:
-                out = run_kind(kind, srcs, rung.backend, h)
+            with obs.span("serve.dispatch", category="serve",
+                          args={"kind": kind, "attempt": a + 1}):
+                if rung.placement != placement:
+                    out = run_default(kind, srcs, rung.backend, h)
+                else:
+                    out = run_kind(kind, srcs, rung.backend, h)
             field, ovf, conv = _norm_run(out)
-            field = np.asarray(field)
+            with obs.span("serve.host_copy", category="serve"):
+                field = np.asarray(field)
             if (plan is not None and field.dtype.kind == "f"
                     and plan.should("nan", kind)):
                 field = field.copy()
                 field.reshape(-1)[0] = np.nan
             if plan is not None and plan.should("straggler", kind):
                 time.sleep(_STRAGGLER_SLEEP_S)
-            _guardrail(kind, field)
+            with obs.span("serve.guardrail", category="serve"):
+                _guardrail(kind, field)
             return field, ovf, conv
 
         def on_retry(a, exc):
@@ -516,10 +532,14 @@ def serve_mixed(g, queries, batch: int, backend: str, hops: int = 3,
         sl = np.asarray([src for _, src, _, _ in live], np.int64)
         srcs = np.concatenate([sl, np.full(batch - len(sl), sl[-1],
                                            sl.dtype)])
-        wd.start(batches)
-        field, ovf, conv, attempts, rung, err = dispatch(kind, srcs)
-        dt = wd.stop()
-        t_done = time.monotonic()
+        with obs.span("serve.flush", category="serve",
+                      args={"kind": kind, "lanes": len(live),
+                            "mixed": mixed_id}) as flush_args:
+            wd.start(batches)
+            field, ovf, conv, attempts, rung, err = dispatch(kind, srcs)
+            dt = wd.stop()
+            t_done = time.monotonic()
+            flush_args["attempts"] = attempts
         batches += 1
         if metrics is not None and wd.median():
             metrics.gauge_max(
@@ -585,41 +605,45 @@ def serve_mixed(g, queries, batch: int, backend: str, hops: int = 3,
             _observe_batch(metrics, kind, batch_lat, len(sl), batch,
                            queue_depth=depth)
 
-    for qid, (kind, src) in enumerate(queries):
-        t_enq = time.monotonic()
-        # input hardening: malformed queries become structured per-query
-        # errors — never an exception that kills the stream
-        if kind not in KINDS:
-            finish(qid, str(kind), src, "error", t_enq,
-                   reason=f"unknown kind {kind!r}; expected one of "
-                          f"{','.join(KINDS)}")
-            continue
-        try:
-            src = int(src)
-        except (TypeError, ValueError):
-            finish(qid, kind, src, "error", t_enq,
-                   reason=f"source {src!r} is not an integer")
-            continue
-        if num_v is not None and not 0 <= src < num_v:
-            finish(qid, kind, src, "error", t_enq,
-                   reason=f"source {src} out of range [0, {num_v})")
-            continue
-        if admission is not None:
-            shed_reason = admission.admit(kind, pending)
-            if shed_reason is not None:
-                finish(qid, kind, src, "shed", t_enq, reason=shed_reason)
+    with obs.span("serve.mixed", category="serve",
+                  args={"id": mixed_id, "queries": n_q}):
+        for qid, (kind, src) in enumerate(queries):
+            t_enq = time.monotonic()
+            # input hardening: malformed queries become structured
+            # per-query errors — never an exception that kills the stream
+            if kind not in KINDS:
+                finish(qid, str(kind), src, "error", t_enq,
+                       reason=f"unknown kind {kind!r}; expected one of "
+                              f"{','.join(KINDS)}")
                 continue
-        dl = None if budget is None else budget.deadline_from(t_enq)
-        pending[kind].append((qid, src, t_enq, dl))
-        if metrics is not None:
-            metrics.gauge_max(
-                "queue_depth_peak",
-                sum(len(p) for p in pending.values()),
-                help="high-water mark of queued-but-unflushed queries")
-        if len(pending[kind]) == batch:
+            try:
+                src = int(src)
+            except (TypeError, ValueError):
+                finish(qid, kind, src, "error", t_enq,
+                       reason=f"source {src!r} is not an integer")
+                continue
+            if num_v is not None and not 0 <= src < num_v:
+                finish(qid, kind, src, "error", t_enq,
+                       reason=f"source {src} out of range [0, {num_v})")
+                continue
+            if admission is not None:
+                shed_reason = admission.admit(kind, pending)
+                if shed_reason is not None:
+                    finish(qid, kind, src, "shed", t_enq,
+                           reason=shed_reason)
+                    continue
+            dl = None if budget is None else budget.deadline_from(t_enq)
+            pending[kind].append((qid, src, t_enq, dl))
+            if metrics is not None:
+                metrics.gauge_max(
+                    "queue_depth_peak",
+                    sum(len(p) for p in pending.values()),
+                    help="high-water mark of queued-but-unflushed "
+                         "queries")
+            if len(pending[kind]) == batch:
+                flush(kind)
+        for kind in KINDS:                   # ragged tails, padded
             flush(kind)
-    for kind in KINDS:                   # ragged tails, padded
-        flush(kind)
     total_s = time.monotonic() - t_start
 
     if validate:                         # oracles off the serving clock
@@ -726,9 +750,17 @@ def main(argv=None):
                     help="write phase spans as Chrome trace-event JSON "
                          "(open at ui.perfetto.dev)")
     args = ap.parse_args(argv)
+    if not args.trace:
+        return _main(args)
+    with obs.capture():
+        try:
+            return _main(args)
+        finally:
+            n_ev = obs.export_chrome_trace(args.trace)
+            log.info(f"wrote {n_ev} trace events to {args.trace}")
 
-    if args.trace:
-        obs.reset()
+
+def _main(args):
     # chaos rig: a seeded REPRO_FAULTS spec installs the fault plan for
     # the whole serving process (no-op when unset)
     plan = inject.install_from_env()
@@ -931,9 +963,6 @@ def main(argv=None):
             with open(args.metrics, "w") as f:
                 f.write(text)
             log.info(f"wrote Prometheus metrics to {args.metrics}")
-    if args.trace:
-        n_ev = obs.export_chrome_trace(args.trace)
-        log.info(f"wrote {n_ev} trace events to {args.trace}")
     if args.json:
         try:
             with open(args.json) as f:

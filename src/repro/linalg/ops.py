@@ -330,6 +330,7 @@ def _ell_or_raise(ell_width, meta, bk: str):
     return None if ell_width is None else int(ell_width)
 
 
+@jax.named_scope("op.spmv")
 def spmv(a, x, *, semiring=plus_times, mask=None, complement: bool = False,
          transpose: bool = False, structural: bool = False,
          ell_width: Optional[int] = None, backend: Optional[str] = None,
@@ -363,6 +364,7 @@ def spmv(a, x, *, semiring=plus_times, mask=None, complement: bool = False,
                                           seg, opos, orow)
 
 
+@jax.named_scope("op.spmm")
 def spmm(a, x, *, semiring=plus_times, mask=None, complement: bool = False,
          transpose: bool = False, structural: bool = False,
          ell_width: Optional[int] = None, backend: Optional[str] = None,
@@ -392,6 +394,7 @@ def spmm(a, x, *, semiring=plus_times, mask=None, complement: bool = False,
                                           seg)
 
 
+@jax.named_scope("op.spmsv")
 def spmsv(a, ids, xvals=None, *, semiring=plus_times, mask=None,
           complement: bool = False, structural: bool = False,
           cap_out: Optional[int] = None, backend: Optional[str] = None,
@@ -460,6 +463,7 @@ def spmsv(a, ids, xvals=None, *, semiring=plus_times, mask=None,
     return _apply_mask(y, _resolve_mask(mask, complement), sr.zero)
 
 
+@jax.named_scope("op.mxm")
 def mxm(a, b, mask, *, semiring=plus_times, b_transpose: bool = False,
         structural: bool = False, cap_out: Optional[int] = None,
         backend: Optional[str] = None,

@@ -5,7 +5,8 @@ Three planes, one package (DESIGN.md §10):
   * ``obs.telemetry`` — on-device per-iteration buffers riding the
     enactor while_loops (frontier size, tier, direction, overflow,
     exchange bytes), read-only by construction.
-  * ``obs.tracing`` — host-side phase spans exportable as Chrome
+  * ``obs.tracing`` — host-side spans, always on the ``jax.profiler``
+    clock, recorded under ``capture()`` and exportable as Chrome
     trace-event JSON (Perfetto), with ``block_until_ready`` fencing.
   * ``obs.metrics`` — streaming log-bucket histograms + counters/gauges
     with Prometheus text exposition for the serving driver.
@@ -18,14 +19,14 @@ from repro.obs.metrics import (Histogram, Metrics, latency_summary,
                                quantile)
 from repro.obs.telemetry import (TelemetryBuffer, TelemetryTrace,
                                  distributed_trace, trim)
-from repro.obs.tracing import (SpanRegistry, export_chrome_trace,
-                               registry, reset, span, timed_span)
+from repro.obs.tracing import (SpanRegistry, capture, export_chrome_trace,
+                               registry, reset, span)
 
 __all__ = [
     "log", "metrics", "telemetry", "tracing",
     "get_logger",
     "Histogram", "Metrics", "latency_summary", "quantile",
     "TelemetryBuffer", "TelemetryTrace", "distributed_trace", "trim",
-    "SpanRegistry", "export_chrome_trace", "registry", "reset", "span",
-    "timed_span",
+    "SpanRegistry", "capture", "export_chrome_trace", "registry", "reset",
+    "span",
 ]

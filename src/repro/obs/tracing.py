@@ -1,31 +1,31 @@
-"""Host-side span tracing: phase timing as Chrome trace events.
+"""Host-side span tracing: program spans on the profiler's clock, and
+phase timing as Chrome trace events.
 
 The device-side telemetry (``obs.telemetry``) answers "what did the BSP
 loop do per iteration"; this module answers "where did the wall clock
-go" — graph build, partition, shard, compile, dispatch, validate — as
-nested spans exportable to the Chrome trace-event JSON format (load the
-file at ``ui.perfetto.dev`` or ``chrome://tracing``).
+go" — graph build, partition, compile, dispatch, the serving engine's
+flushes and host copies, validate.
 
-  * ``span("compile", args={"primitive": "bfs"})`` — a context manager
-    timing its block with ``time.perf_counter_ns``. Spans nest; each
-    records (name, category, start, duration, thread) into the ambient
-    ``SpanRegistry``.
+  * ``span("serve.flush", args={"kind": "bfs"})`` — a context manager
+    that always opens a ``jax.profiler.TraceAnnotation`` (its ``args``
+    become the annotation's stats), so the span lands on the host plane
+    of any ``jax.profiler`` capture, on the same clock as the device's
+    operations. With no profiler running the annotation is inert.
+  * ``capture()`` — while one is open, spans are also timed with
+    ``time.perf_counter_ns`` into the ambient ``SpanRegistry``
+    (``--trace`` on graph_run/graph_serve, ``chip_smoke.py``). Outside a
+    capture nothing is recorded, so a long-lived server never grows it.
   * Async-dispatch fencing: JAX returns before the device finishes, so
     a span that should measure execution must fence. Pass the result
     pytree via ``sync=``: ``jax.block_until_ready`` runs INSIDE the
     span, immediately before the end stamp.
   * ``export_chrome_trace(path)`` writes ``{"traceEvents": [...]}``
     with complete ("ph": "X") events, microsecond timestamps.
-  * ``REPRO_TRACE_JAX=1`` additionally wraps every span in
-    ``jax.profiler.TraceAnnotation`` so span names land inside a
-    ``jax.profiler.trace`` capture (the opt-in bridge; a missing or
-    drifted profiler API degrades to host-only spans, never an error).
 
 Span taxonomy (DESIGN.md §10): category "setup" for build/partition/
 shard, "compile" for first-trace runs, "dispatch" for steady-state
 execution, "validate" for oracle checks, "serve" for serving-loop
-phases. The registry is per-process and explicitly clearable
-(``reset()``) so drivers emit one file per run.
+phases.
 """
 from __future__ import annotations
 
@@ -36,6 +36,8 @@ import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
+
+import jax
 
 
 @dataclass
@@ -83,6 +85,8 @@ class SpanRegistry:
 
 
 _registry = SpanRegistry()
+_captures = 0          # open captures; spans record only while > 0
+_captures_lock = threading.Lock()
 
 
 def registry() -> SpanRegistry:
@@ -94,56 +98,49 @@ def reset() -> None:
     _registry.reset()
 
 
-def _jax_annotation(name: str):
-    """The opt-in ``jax.profiler`` bridge: a TraceAnnotation context for
-    ``name`` when REPRO_TRACE_JAX is set and the API exists, else None.
-    Never raises — profiler API drift degrades to host-only spans."""
-    if os.environ.get("REPRO_TRACE_JAX", "") not in ("1", "true"):
-        return None
+@contextmanager
+def capture():
+    """Record spans into the registry while open. The outermost capture
+    starts from an empty registry; what it recorded stays readable after
+    it closes, until the next one opens."""
+    global _captures
+    with _captures_lock:
+        if _captures == 0:
+            _registry.reset()
+        _captures += 1
     try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
-    except Exception:
-        return None
+        yield _registry
+    finally:
+        with _captures_lock:
+            _captures -= 1
 
 
 @contextmanager
 def span(name: str, category: str = "phase",
-         args: Optional[Dict[str, Any]] = None, sync=None,
-         into: Optional[SpanRegistry] = None):
-    """Time a block as one span. ``sync`` is a pytree fenced with
-    ``jax.block_until_ready`` before the end stamp (async dispatch
-    would otherwise end the span at enqueue time, not completion)."""
-    reg = into if into is not None else _registry
-    bridge = _jax_annotation(name)
-    if bridge is not None:
-        bridge.__enter__()
-    t0 = time.perf_counter_ns()
-    try:
-        yield reg
-    finally:
-        if sync is not None:
-            import jax
-            jax.block_until_ready(sync)
-        dur = time.perf_counter_ns() - t0
-        if bridge is not None:
-            bridge.__exit__(None, None, None)
-        reg.add(SpanEvent(name=name, category=category, start_ns=t0,
-                          duration_ns=dur,
-                          thread_id=threading.get_ident(),
-                          args=dict(args or {})))
-
-
-@contextmanager
-def timed_span(name: str, **kw):
-    """``span`` that also hands back the duration: yields a dict whose
-    ``"ms"`` key is filled at exit (for drivers that print the phase
-    time as well as tracing it)."""
-    out: Dict[str, float] = {}
-    t0 = time.perf_counter_ns()
-    with span(name, **kw):
-        yield out
-    out["ms"] = (time.perf_counter_ns() - t0) / 1e6
+         args: Optional[Dict[str, Any]] = None, sync=None):
+    """Run a block as one span: a profiler annotation always, and a
+    registry event while a capture is open. Yields a dict: args the
+    block puts there (known only at its end) are added at exit. ``sync``
+    is a pytree fenced with ``jax.block_until_ready`` before the end
+    stamp (async dispatch would otherwise end the span at enqueue time,
+    not completion)."""
+    args = dict(args or {})
+    late: Dict[str, Any] = {}
+    with jax.profiler.TraceAnnotation(name, **args) as annotation:
+        t0 = time.perf_counter_ns()
+        try:
+            yield late
+        finally:
+            if sync is not None:
+                jax.block_until_ready(sync)
+            if late:
+                annotation.set_metadata(**late)
+            if _captures:
+                _registry.add(SpanEvent(
+                    name=name, category=category, start_ns=t0,
+                    duration_ns=time.perf_counter_ns() - t0,
+                    thread_id=threading.get_ident(),
+                    args={**args, **late}))
 
 
 def export_chrome_trace(path: str,

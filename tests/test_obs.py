@@ -222,10 +222,11 @@ def test_metrics_render_parseable_prometheus():
 
 def test_span_registry_and_chrome_export(tmp_path):
     obs.reset()
-    with obs.span("outer", category="setup"):
-        with obs.span("inner", category="dispatch",
-                      args={"k": 1}):
-            pass
+    with obs.capture():
+        with obs.span("outer", category="setup"):
+            with obs.span("inner", category="dispatch",
+                          args={"k": 1}):
+                pass
     events = obs.registry().events
     assert [e.name for e in events] == ["inner", "outer"]
     out = tmp_path / "trace.json"
@@ -240,6 +241,52 @@ def test_span_registry_and_chrome_export(tmp_path):
     assert inner["args"] == {"k": 1}
     obs.reset()
     assert not obs.registry().events
+
+
+def test_span_records_only_under_a_capture():
+    obs.reset()
+    with obs.span("outside", args={"k": 1}):
+        pass
+    assert not obs.registry().events
+    with obs.capture():
+        with obs.capture():           # nested: one registry, no reset
+            with obs.span("first"):
+                pass
+        with obs.span("second") as late:
+            late["attempts"] = 2
+    with obs.span("after"):
+        pass
+    ev = obs.registry().events
+    assert [e.name for e in ev] == ["first", "second"]
+    assert ev[1].args == {"attempts": 2}
+    with obs.capture():               # a new capture starts empty
+        pass
+    assert not obs.registry().events
+
+
+def test_span_lands_on_the_profilers_host_plane(tmp_path):
+    """A span is a ``jax.profiler`` annotation: it shows on the host
+    plane of a capture, its args (the late ones too) as the event's
+    stats, while the span registry stays empty without ``capture()``."""
+    import glob
+
+    import jax
+
+    obs.reset()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("serve.flush", args={"kind": "bfs", "lanes": 3}) as a:
+            a["attempts"] = 1
+    finally:
+        jax.profiler.stop_trace()
+    assert not obs.registry().events
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = [dict(e.stats)
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name == "serve.flush"]
+    assert found == [{"kind": "bfs", "lanes": 3, "attempts": 1}]
 
 
 # ---------------------------------------------------------------------- log
