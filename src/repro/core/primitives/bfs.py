@@ -11,8 +11,10 @@ iteration (expansion + visited test + exact first-occurrence culling +
 compaction in a single op — paper §5.3's fusion applied to the whole
 step), run at the smallest power-of-two capacity tier that holds the
 frontier's degree sum (``enactor.tiered_step``), so an iteration's cost
-tracks the live frontier instead of worst-case m. In-op culling is
-exact for free (the bitmap is already in hand), which makes
+tracks the live frontier instead of worst-case m. In a batch's mixed
+step (some lanes pull, some push) that degree sum is the push lanes'
+alone: the pull lanes' large frontiers never size the push. In-op
+culling is exact for free (the bitmap is already in hand), which makes
 ``idempotence`` moot there; the flag keeps selecting hash-vs-exact
 uniquify on the unfused TWC/THREAD ablation path.
 
@@ -258,8 +260,15 @@ def _bfs_impl(graph: Graph, srcs: jax.Array, do_a: float, do_b: float,
         @jax.named_scope("mixed")
         def mixed_step(st):
             # lanes disagree: compute both directions in lockstep and
-            # select per lane
-            return select_lanes(mode == PULL, pull_step(st), push_step(st))
+            # select per lane. The push half sees only the push lanes'
+            # frontiers (pull lanes' lengths zeroed), so its rung follows
+            # their workload, not the pull lanes' large frontiers; what
+            # it computes for the pull lanes is discarded either way.
+            pull = mode == PULL
+            fr = st.frontier
+            pushed = st._replace(frontier=BatchedSparseFrontier(
+                ids=fr.ids, lengths=jnp.where(pull, 0, fr.lengths)))
+            return select_lanes(pull, pull_step(st), push_step(pushed))
 
         # direction decisions correlate strongly across lanes (shared
         # topology), so branch on the homogeneous cases and pay the
@@ -279,24 +288,30 @@ def _bfs_impl(graph: Graph, srcs: jax.Array, do_a: float, do_b: float,
     buf = None
     if telemetry:
         # read-only probe: per-lane frontier size / direction / overflow
-        # delta after each step, plus the tier rung the step's workload
-        # selected (recomputed from the prev frontier — XLA CSEs it
-        # against the dispatch in push_step, so it costs nothing).
+        # delta after each step; the tier rung the push half ran at,
+        # recomputed from the prev frontier of the lanes that pushed (the
+        # bottom rung when none did); and whether the step was mixed
+        # (active lanes ran both directions).
         from ...obs.telemetry import TelemetryBuffer
         from ..frontier import tier_index
         caps_arr = jnp.asarray(caps_e, jnp.int32)
 
         def probe(prev: BFSState, new: BFSState) -> dict:
-            need = jnp.max(ops.frontier_workload(graph, prev.frontier))
+            push = new.mode == PUSH
+            work = ops.frontier_workload(graph, prev.frontier)
+            need = jnp.max(jnp.where(push, work, 0))
             tier = caps_arr[tier_index(need, caps_e)]
+            active = prev.n_f > 0
+            mixed = jnp.any(active & push) & jnp.any(active & ~push)
             return {"frontier": new.n_f, "tier": tier,
-                    "direction": new.mode,
+                    "direction": new.mode, "mixed": mixed,
                     "overflow": new.overflow - prev.overflow}
 
         buf0 = TelemetryBuffer.make(n + 1, {
             "frontier": ((b,), jnp.int32),
             "tier": ((), jnp.int32),
             "direction": ((b,), jnp.int32),
+            "mixed": ((), jnp.int32),
             "overflow": ((b,), jnp.int32)})
         final, lane_iters, _, buf = run_until_any(
             lambda st: st.n_f > 0, body, state, max_iter=mi,
@@ -336,9 +351,10 @@ def bfs_batch(graph: Graph, srcs, *, direction: bool = True,
     benchmarking.
 
     ``telemetry=True`` returns ``(BFSResult, TelemetryBuffer)`` — the
-    buffer holds per-iteration frontier size / tier / direction /
-    overflow columns (``obs.telemetry.trim`` converts to host arrays);
-    the result itself is bit-identical to ``telemetry=False``.
+    buffer holds per-iteration frontier size / tier (the push's rung) /
+    direction / mixed (0/1) / overflow columns (``obs.telemetry.trim``
+    converts to host arrays); the result itself is bit-identical to
+    ``telemetry=False``.
 
     ``budget`` (``repro.ft.Budget``) caps BSP iterations per query: lanes
     cut short come back with partial labels and ``converged=False``; the

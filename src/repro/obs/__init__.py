@@ -3,8 +3,8 @@
 Three planes, one package (DESIGN.md §10):
 
   * ``obs.telemetry`` — on-device per-iteration buffers riding the
-    enactor while_loops (frontier size, tier, direction, overflow,
-    exchange bytes), read-only by construction.
+    enactor while_loops (frontier size, tier, direction, mixed step,
+    overflow, exchange bytes), read-only by construction.
   * ``obs.tracing`` — host-side spans, always on the ``jax.profiler``
     clock, recorded under ``capture()`` and exportable as Chrome
     trace-event JSON (Perfetto), with ``block_until_ready`` fencing.

@@ -10,11 +10,15 @@ Contracts under test:
     the pinned top tier, on both backends, with frontier sizes
     straddling the tier ladder's rungs (the rmat fixture's BFS crosses
     512 within two hops);
+  * a batch's mixed direction step sizes its push by the push lanes'
+    workload alone, with results unchanged;
   * tuner: clamped default heuristic, cache round trip, env switches.
 """
+import importlib
 import json
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -25,8 +29,9 @@ from repro.core import graph as G
 from repro.core import operators as ops
 from repro.core import ref as R
 from repro.core.enactor import tiered_step
-from repro.core.primitives import bfs_batch, sssp_batch
+from repro.core.primitives import bfs, bfs_batch, sssp_batch
 from repro.kernels import runtime, tuner
+from repro.obs import telemetry as T
 
 BACKENDS = ["xla", "pallas"]
 
@@ -214,6 +219,106 @@ def test_sssp_tiered_bitmatch(rmat_graph, high_degree_src, backend):
                               np.asarray(getattr(ru, f))), (f, backend)
     assert np.allclose(np.asarray(rt.dist[0]),
                        R.sssp_ref(g, high_degree_src), rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the mixed direction step: its push half runs at the push lanes' rung
+# ---------------------------------------------------------------------------
+
+
+def _hub_and_leaves(g):
+    """The hub pulls from its second step on while degree-1 sources
+    still push, so the batch takes at least one mixed step."""
+    deg = np.diff(np.asarray(g.row_offsets))
+    leaves = [int(v) for v in np.argsort(deg, kind="stable") if deg[v] > 0]
+    return [int(np.argmax(deg))] + leaves[:2]
+
+
+def _push_lane_needs(g, res, trace):
+    """Per step: the largest degree sum over the lanes that pushed (the
+    vertices at depth t are the frontier step t expands), the largest
+    over every lane, and whether any active lane pushed."""
+    deg = np.diff(np.asarray(g.row_offsets))
+    labels = np.asarray(res.labels)
+    iters = np.asarray(res.iterations)
+    rows = []
+    for t in range(trace.steps):
+        work = np.array([deg[lab == t].sum() for lab in labels])
+        push = (iters > t) & (trace["direction"][t] == 0)
+        rows.append((int(work[push].max(initial=0)), int(work.max()),
+                     bool(push.any())))
+    return rows
+
+
+def _rung(caps, need):
+    return caps[int(F.tier_index(jnp.int32(need), caps))]
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_bfs_mixed_step_bitmatch(rmat_graph, backend):
+    """A batch whose lanes disagree on direction gives the same labels,
+    preds and iterations as the pinned top tier and as each source run
+    on its own."""
+    g = rmat_graph
+    srcs = _hub_and_leaves(g)
+    rt, buf = bfs_batch(g, srcs, backend=backend, telemetry=True)
+    trace = T.trim(buf, np.asarray(rt.iterations))
+    assert trace["mixed"].any()
+    ru = bfs_batch(g, srcs, backend=backend, tiered=False)
+    for f in rt._fields:
+        assert np.array_equal(np.asarray(getattr(rt, f)),
+                              np.asarray(getattr(ru, f))), (f, backend)
+    for i, s in enumerate(srcs):
+        one = bfs(g, s, backend=backend)
+        for f in ("labels", "preds", "iterations"):
+            assert np.array_equal(np.asarray(getattr(rt, f)[i]),
+                                  np.asarray(getattr(one, f))), (f, i)
+
+
+def test_bfs_mixed_step_tier_follows_push_lanes(rmat_graph):
+    """The telemetry rung of a mixed step is the push lanes' own rung,
+    strictly below the rung every lane's frontier would pick."""
+    g = rmat_graph
+    caps = B.tier_plan("advance_filter", g.num_edges)
+    r, buf = bfs_batch(g, _hub_and_leaves(g), telemetry=True)
+    trace = T.trim(buf, np.asarray(r.iterations))
+    mixed = np.flatnonzero(trace["mixed"])
+    assert len(mixed) > 0
+    rows = _push_lane_needs(g, r, trace)
+    below = 0
+    for t in mixed:
+        push_need, all_need, _ = rows[t]
+        assert trace["tier"][t] <= _rung(caps, push_need), t
+        below += int(trace["tier"][t] < _rung(caps, all_need))
+    assert below > 0
+
+
+def test_bfs_mixed_step_dispatches_push_lanes_workload(rmat_graph,
+                                                       monkeypatch):
+    """The workload the push dispatch switches on, read from inside the
+    compiled loop, is the push lanes' largest degree sum in every step
+    that pushes: the pull lanes' frontiers never size the push."""
+    bfs_mod = importlib.import_module("repro.core.primitives.bfs")
+    seen = []
+
+    def spy(need, caps, step_of, state, **kw):
+        jax.debug.callback(lambda x: seen.append(int(x)), need,
+                           ordered=True)
+        return tiered_step(need, caps, step_of, state, **kw)
+
+    g = rmat_graph
+    monkeypatch.setattr(bfs_mod, "tiered_step", spy)
+    bfs_mod._bfs_impl.clear_cache()
+    try:
+        r, buf = bfs_batch(g, _hub_and_leaves(g), telemetry=True)
+        jax.effects_barrier()
+    finally:
+        bfs_mod._bfs_impl.clear_cache()
+    trace = T.trim(buf, np.asarray(r.iterations))
+    assert trace["mixed"].any()
+    want = [need for need, _, pushed in _push_lane_needs(g, r, trace)
+            if pushed]
+    assert seen == want
 
 
 # ---------------------------------------------------------------------------
