@@ -35,25 +35,33 @@ under ``shard_map`` with two exchange strategies:
 Traversal loops (BFS / SSSP / CC) run whole-loop inside one shard_map
 with replicated (n,)-sized state and local edge sweeps; every state
 update is an exact min/OR combine, so labels and distances bit-match
-the single-device primitives. All impls are module-level jits with the
-mesh as a static argument — repeated calls (the serving driver) reuse
-one trace per (shape, mesh).
+the single-device primitives. The 2-D BFS runs a (B,) batch of sources
+in one loop ((B, n) state, one program per batch); the 1-D BFS and
+both SSSP placements serve one source per program. All impls are
+module-level jits with the mesh as a static argument — repeated calls
+(the serving driver) reuse one trace per (shape, mesh).
 
 placement="2d" (the vertex-cut R×C mesh, ``partition_2d``) registers a
 second provider family with different exchange geometry:
 
-  * "advance"/"advance_filter" (2d) — chunked bitmask exchange: device
-    (i, j) expands its edge block into a ceil(n/C) *column-chunk* mask,
-    the R devices of each mesh column psum-OR their chunks (row-axis
-    collective), and the C chunks all-gather along the column axis into
-    the global mask (the mirror-merge: every mirror's discoveries fold
-    into the owner chunk's lane). The chunk exchange is DOUBLE-BUFFERED
-    over static edge tiles — the psum for tile t is consumed one loop
+  * "advance"/"advance_filter" (2d) — batched chunked bitmask
+    exchange: device (i, j) expands its edge block from a (B, n) batch
+    of frontiers into (B, vpc) *column-chunk* masks, the R devices
+    of each mesh column psum-OR their chunks (row-axis collective), and
+    the C chunks all-gather along the column axis into the global
+    (B, n) mask (the mirror-merge: every mirror's discoveries fold into
+    the owner chunk's lane). The chunk exchange is DOUBLE-BUFFERED over
+    static edge tiles — the psum for tile t is consumed one loop
     iteration after it is issued, so tile t+1's local gathers overlap
     the collective (XLA overlaps the in-flight psum with the next
     tile's scatter; OR is idempotent and order-free, so the overlap
     cannot change bits). Per-device bytes/step drop from the 1-D
-    2·(p−1)/p·n·4 to tiles·2·(R−1)/R·vpc + (C−1)·vpc uint8 lanes.
+    2·(p−1)/p·n·4 per source to
+    B·(tiles·2·(R−1)/R·vpc + (C−1)·vpc) uint8 lanes for a batch. The
+    block slots' source rows are computed once per program
+    (``_block_slots``), outside the level loop. The local expansion
+    runs under ``op.advance_filter`` (or ``op.advance``), the row psum
+    and the column all-gather under ``op.exchange``.
   * "spmv"/"spmm" (2d) — pre-fold product exchange: each device
     computes its block's per-edge products (bit-identical IEEE ops),
     scatters them at their ``Blocks2D.epos`` slots into one
@@ -75,13 +83,14 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
 from . import backend as B
 from .partition import (Partitioned2DGraph, PartitionedGraph,
-                        check_mesh_axes, check_mesh_axis)
+                        check_mesh_axes, check_mesh_axis, unpad_chunks)
 
 # a plain Python int on purpose: this module is imported LAZILY by the
 # registry, possibly in the middle of someone else's jit trace, and a
@@ -90,8 +99,8 @@ INT_BIG = 2 ** 30
 
 
 class DistBFSResult(NamedTuple):
-    labels: jax.Array      # (n,) global depths
-    iterations: jax.Array
+    labels: jax.Array      # (B, n) global depths; (n,) for one source
+    iterations: jax.Array  # (B,) per-lane BSP iterations; () for one
 
 
 class DistSSSPResult(NamedTuple):
@@ -172,12 +181,20 @@ def _all_reduce(sr, x: jax.Array, axis: str) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+def _slot_rows(offsets: jax.Array, slots: int, rows: int) -> jax.Array:
+    """Local source row of each of ``slots`` CSR slots, clamped to
+    [0, rows − 1] (pad slots past ``offsets[-1]`` land on the last row):
+    the cumsum form of ``clip(searchsorted(offsets, slot, 'right') − 1)``
+    (``graph.row_segments_of``), bit-identical to it and O(slots)."""
+    from .graph import row_segments_of
+    return jnp.minimum(row_segments_of(offsets, slots), rows - 1)
+
+
 def _local_slots(local_ro: jax.Array, local_ci: jax.Array, vpp: int):
     """Map local CSR slots back to (local source row, validity)."""
     me = local_ci.shape[0]
     slot = jnp.arange(me, dtype=jnp.int32)
-    src_local = jnp.searchsorted(local_ro, slot, side="right") - 1
-    src_local = jnp.clip(src_local, 0, vpp - 1).astype(jnp.int32)
+    src_local = _slot_rows(local_ro, me, vpp)
     valid = (slot < local_ro[-1]) & (local_ci >= 0)
     return src_local, valid
 
@@ -187,7 +204,7 @@ def _local_expand_mask(local_ro, local_ci, frontier_slice, n, vpp):
 
     frontier_slice: (vpp,) bool of owned active vertices.
     Dense formulation: every local CSR slot whose source vertex is active
-    marks its destination. Source of local slot e = searchsorted(ro, e).
+    marks its destination.
     """
     src_local, valid = _local_slots(local_ro, local_ci, vpp)
     active = frontier_slice[src_local] & valid
@@ -203,14 +220,15 @@ def _local_expand_mask(local_ro, local_ci, frontier_slice, n, vpp):
 
 
 def _owned_slice(vec: jax.Array, base, vpp: int, fill=0):
-    """The (vpp,) owned slice of a replicated vector, correct for the
-    padded tail part: ``dynamic_slice`` CLAMPS an out-of-range start, so
-    slicing (n,) state directly would hand the tail part a shifted
-    window whenever p·vpp > n — pad by one part first so every start is
-    in range (pad lanes belong to no real row and never survive the
-    validity masks)."""
-    padded = jnp.pad(vec, (0, vpp), constant_values=fill)
-    return jax.lax.dynamic_slice(padded, (base,), (vpp,))
+    """The (…, vpp) owned slice of a replicated (…, n) vector along its
+    last axis, correct for the padded tail part: ``dynamic_slice``
+    CLAMPS an out-of-range start, so slicing (n,) state directly would
+    hand the tail part a shifted window whenever p·vpp > n — pad by one
+    part first so every start is in range (pad lanes belong to no real
+    row and never survive the validity masks)."""
+    pad = [(0, 0)] * (vec.ndim - 1) + [(0, vpp)]
+    padded = jnp.pad(vec, pad, constant_values=fill)
+    return jax.lax.dynamic_slice_in_dim(padded, base, vpp, axis=-1)
 
 
 @B.register("advance", B.XLA, B.SHARDED)
@@ -399,44 +417,52 @@ def _mxm_sharded(a_off, a_idx, a_vals, bt_off, bt_idx, bt_vals,
 
 def _block_slots(block_ro, block_ci, vpr: int):
     """(local source row, validity) of every block CSR slot — the block
-    twin of ``_local_slots``."""
+    twin of ``_local_slots``. Traversals build it once per program,
+    outside their level loop, and hand it to the providers."""
     return _local_slots(block_ro, block_ci, vpr)
 
 
-def _block_discover_chunk(block_ro, block_ci, frontier, row_base,
-                          col_base, vpr: int, vpc: int, row_ax: str,
-                          tiles: int):
+def _block_discover_chunk(slots, block_ci, frontier, row_base, col_base,
+                          vpr: int, vpc: int, row_ax: str, tiles: int):
     """The per-device half of the 2-D bitmask exchange: expand this
-    block's edges from the owned frontier slice into a (vpc,) column
-    chunk mask, psum-OR'd along the mesh row — double-buffered over
-    ``tiles`` static edge tiles so the collective for tile t is in
-    flight while tile t+1's local gathers run (OR is idempotent and
-    order-free, so the overlap cannot change bits; a tile's clamped
-    re-read at the ragged tail re-marks targets idempotently for the
-    same reason). uint8 lanes keep the exchange byte-proportional to
-    the chunk, not to n."""
-    src_local, valid = _block_slots(block_ro, block_ci, vpr)
-    my_src = _owned_slice(frontier, row_base, vpr)
-    active = my_src[src_local] & valid
-    # local column-chunk target of every block edge; inactive ⇒ vpc
-    # (dropped by the scatter)
-    tgt = jnp.where(active, block_ci - col_base, vpc).astype(jnp.int32)
+    block's edges from the owned slice of every lane's frontier
+    (``frontier`` (B, n)) into (B, vpc) column-chunk masks, psum-OR'd
+    along the mesh row — double-buffered over ``tiles`` static edge
+    tiles so the collective for tile t is in flight while tile t+1's
+    local gathers run (OR is idempotent and order-free, so the overlap
+    cannot change bits; a tile's clamped re-read at the ragged tail
+    re-marks targets idempotently for the same reason). uint8 lanes
+    keep the exchange byte-proportional to the chunk, not to n."""
+    src_rows, valid = slots
+    b = frontier.shape[0]
+    # lanes minor: one index gathers a slot's B frontier bits and one
+    # scatter-max marks its target in all B lanes
+    my_src = _owned_slice(frontier, row_base, vpr).T.astype(jnp.uint8)
     be = int(block_ci.shape[0])
     tiles = max(int(tiles), 1)
     ept = max(-(-be // tiles), 1)
 
     def tile_mask(t):
-        sl = jax.lax.dynamic_slice(tgt, (t * ept,), (ept,))
-        return jnp.zeros((vpc,), jnp.uint8).at[sl].set(1, mode="drop")
+        def cut(x):
+            return jax.lax.dynamic_slice(x, (t * ept,), (ept,))
+        active = my_src[cut(src_rows)]                       # (ept, B)
+        # an invalid (pad) slot targets vpc and is dropped
+        tgt = jnp.where(cut(valid), cut(block_ci) - col_base, vpc)
+        return jnp.zeros((vpc, b), jnp.uint8).at[tgt].max(
+            active, mode="drop").T
+
+    def row_or(mask):
+        with jax.named_scope("op.exchange"):
+            return jax.lax.psum(mask, row_ax)
 
     def body(t, carry):
         acc, inflight = carry
         cur = tile_mask(t)                 # local gathers for tile t …
         acc = jnp.maximum(acc, inflight)   # … overlap tile t−1's psum
-        return acc, jax.lax.psum(cur, row_ax)
+        return acc, row_or(cur)
 
-    inflight0 = jax.lax.psum(tile_mask(0), row_ax)
-    acc0 = jnp.zeros((vpc,), jnp.uint8)
+    inflight0 = row_or(tile_mask(0))
+    acc0 = jnp.zeros((b, vpc), jnp.uint8)
     if tiles > 1:
         acc, inflight = jax.lax.fori_loop(1, tiles, body,
                                           (acc0, inflight0))
@@ -445,45 +471,54 @@ def _block_discover_chunk(block_ro, block_ci, frontier, row_base,
     return jnp.maximum(acc, inflight) > 0
 
 
-def _gather_chunks(chunk, col_ax: str, n: int):
-    """Column-axis mirror-merge: assemble the global (n,) vector from
-    the C per-chunk lanes (each chunk is already the exact row-combined
-    value for its vertices — concatenate and trim the ceil padding)."""
-    full = jax.lax.all_gather(chunk, col_ax, axis=0, tiled=False)
-    return full.reshape(-1)[:n]
+def _gather_chunks(chunk, col_ax: str, col_sizes: tuple):
+    """Column-axis mirror-merge: assemble the global (…, n) vector from
+    the C per-chunk lanes along the last axis (each chunk is already the
+    exact row-combined value for its vertices — concatenate and drop
+    each chunk's padding past its ``col_sizes`` vertices)."""
+    with jax.named_scope("op.exchange"):
+        full = jax.lax.all_gather(chunk, col_ax, axis=chunk.ndim - 1,
+                                  tiled=True)
+    return unpad_chunks(full, int(chunk.shape[-1]), col_sizes)
 
 
 @B.register("advance", B.XLA, B.TWOD)
-def _advance_2d(block_ro, block_ci, frontier, row_base, col_base,
-                vpr: int, vpc: int, axes: tuple,
+@jax.named_scope("op.advance")
+def _advance_2d(slots, block_ci, frontier, row_base, col_base,
+                vpr: int, col_sizes: tuple, axes: tuple,
                 tiles: int = DEFAULT_EXCHANGE_TILES):
-    """2-D chunked bitmask-exchange advance. Must be called inside an
-    active shard_map over both mesh axes. Contract:
-      (block_ro (vpr+1,), block_ci (be,), frontier (n,), row_base (),
-       col_base (), vpr, vpc, axes, tiles) → (n,) bool discovered mask,
-    already row-psum'd and column-gathered (identical on every
-    device)."""
+    """2-D chunked bitmask-exchange advance over a batch of frontiers.
+    Must be called inside an active shard_map over both mesh axes.
+    Contract:
+      (slots = _block_slots(block_ro, block_ci, vpr), block_ci (be,),
+       frontier (B, n), row_base (), col_base (), vpr, col_sizes (the
+       C column chunks' vertex counts), axes, tiles) → (B, n) bool
+    discovered masks, already row-psum'd and column-gathered (identical
+    on every device)."""
     row_ax, col_ax = axes
-    chunk = _block_discover_chunk(block_ro, block_ci, frontier, row_base,
-                                  col_base, vpr, vpc, row_ax, tiles)
-    return _gather_chunks(chunk, col_ax, int(frontier.shape[0]))
+    chunk = _block_discover_chunk(slots, block_ci, frontier, row_base,
+                                  col_base, vpr, max(col_sizes), row_ax,
+                                  tiles)
+    return _gather_chunks(chunk, col_ax, col_sizes)
 
 
 @B.register("advance_filter", B.XLA, B.TWOD)
-def _advance_filter_2d(block_ro, block_ci, frontier, visited, row_base,
-                       col_base, vpr: int, vpc: int, axes: tuple,
+@jax.named_scope("op.advance_filter")
+def _advance_filter_2d(slots, block_ci, frontier, visited, row_base,
+                       col_base, vpr: int, col_sizes: tuple, axes: tuple,
                        tiles: int = DEFAULT_EXCHANGE_TILES):
     """Fused 2-D advance+filter: the visited filter applies to the
-    merged column chunk BEFORE the column-axis gather, so the filter
+    merged column chunks BEFORE the column-axis gather, so the filter
     costs no extra exchange (the 2-D analogue of the single-device
     fused megakernel). Same contract as the 2d "advance" plus the
-    replicated (n,) visited mask; returns the new frontier."""
+    replicated (B, n) visited masks; returns the (B, n) new
+    frontiers."""
     row_ax, col_ax = axes
-    chunk = _block_discover_chunk(block_ro, block_ci, frontier, row_base,
+    vpc = max(col_sizes)
+    chunk = _block_discover_chunk(slots, block_ci, frontier, row_base,
                                   col_base, vpr, vpc, row_ax, tiles)
     my_visited = _owned_slice(visited, col_base, vpc)
-    return _gather_chunks(chunk & ~my_visited, col_ax,
-                          int(frontier.shape[0]))
+    return _gather_chunks(chunk & ~my_visited, col_ax, col_sizes)
 
 
 def _merge_block_products(store_leaf, valid, prod, sr, emax: int,
@@ -517,7 +552,6 @@ def _spmv_2d(offsets, store, values, x, sr, ell_width, mask,
     mesh, axes = _require_2d_mesh()
     row_ax, col_ax = axes
     vpr = int(offsets.shape[2]) - 1
-    n = int(x.shape[0])
     emax = int(store.chunk_emax)
     blk, rep = P(row_ax, col_ax), P()
 
@@ -545,7 +579,7 @@ def _spmv_2d(offsets, store, values, x, sr, ell_width, mask,
                         in_specs=(blk, blk, blk, rep),
                         out_specs=P(row_ax), check_vma=False)
         y = run(offsets, store, values, x)
-    y = y[:n]
+    y = unpad_chunks(y, vpr, store.row_sizes)
     if mask is not None:
         y = jnp.where(mask, y, sr.zero)
     return y.astype(jnp.float32)
@@ -562,7 +596,6 @@ def _spmm_2d(offsets, store, values, x, sr, ell_width, mask,
     mesh, axes = _require_2d_mesh()
     row_ax, col_ax = axes
     vpr = int(offsets.shape[2]) - 1
-    n = int(x.shape[0])
     emax = int(store.chunk_emax)
     blk, rep = P(row_ax, col_ax), P()
 
@@ -574,9 +607,7 @@ def _spmm_2d(offsets, store, values, x, sr, ell_width, mask,
         prod = xv if ev is None else sr.mul_op(ev[:, None], xv)
         prod = jnp.where(valid[:, None], prod, sr.zero)
         merged = _merge_block_products(ep, valid, prod, sr, emax, col_ax)
-        slot = jnp.arange(emax, dtype=jnp.int32)
-        seg = jnp.clip(jnp.searchsorted(cro, slot, side="right") - 1,
-                       0, vpr - 1).astype(jnp.int32)
+        seg = _slot_rows(cro, emax, vpr)
         y = sr.segment_reduce(merged, seg, vpr, indices_are_sorted=True)
         deg = cro[1:] - cro[:-1]
         return jnp.where((deg > 0)[:, None], y, sr.zero)
@@ -591,7 +622,7 @@ def _spmm_2d(offsets, store, values, x, sr, ell_width, mask,
                         in_specs=(blk, blk, blk, rep),
                         out_specs=P(row_ax), check_vma=False)
         y = run(offsets, store, values, x)
-    y = y[:n]
+    y = unpad_chunks(y, vpr, store.row_sizes, axis=0)
     if mask is not None:
         y = jnp.where(mask[:, None], y, sr.zero)
     return y.astype(jnp.float32)
@@ -615,6 +646,7 @@ def _mxm_2d(a_off, a_store, a_vals, bt_off, bt_idx, bt_vals,
     vpr = int(a_off.shape[2]) - 1
     e = int(base.shape[0])
     a_idx = a_store.cols if hasattr(a_store, "cols") else a_store
+    starts = np.cumsum((0,) + a_store.row_sizes[:-1])   # row chunks' 1st ids
     blk, rep = P(row_ax, col_ax), P()
     has_av = a_vals is not None
     has_btv = bt_vals is not None
@@ -625,7 +657,8 @@ def _mxm_2d(a_off, a_store, a_vals, bt_off, bt_idx, bt_vals,
     def local(ao_s, ai_s, av_s, bto, bti, btv, base_g, rows_g):
         ao, ai = ao_s[0, 0], ai_s[0, 0]
         me = int(ai.shape[0])
-        my_base = jax.lax.axis_index(row_ax).astype(jnp.int32) * vpr
+        my_base = jnp.asarray(starts, jnp.int32)[
+            jax.lax.axis_index(row_ax)]
         owned = (base_g >= my_base) & (base_g < my_base + vpr)
         base_l = jnp.where(owned, base_g - my_base, 0)
         deg = ao[base_l + 1] - ao[base_l]       # this block's slice only
@@ -700,14 +733,19 @@ def _bfs_dist_impl(ro, ci, base, src, *, n: int, vpp: int, mesh: Mesh,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n", "vpr", "vpc", "mesh", "axes",
-                                    "tiles", "backend"))
-def _bfs_2d_impl(ro, ci, row_base, col_base, src, *, n: int, vpr: int,
-                 vpc: int, mesh: Mesh, axes: tuple, tiles: int,
+                   static_argnames=("n", "vpr", "col_sizes", "mesh",
+                                    "axes", "tiles", "backend"))
+def _bfs_2d_impl(ro, ci, row_base, col_base, srcs, *, n: int, vpr: int,
+                 col_sizes: tuple, mesh: Mesh, axes: tuple, tiles: int,
                  backend: str):
+    """One BFS per lane of ``srcs`` (B,), all lanes in one level loop
+    until every lane's frontier is empty; returns (B, n) depths and the
+    (B,) levels each lane ran with a non-empty frontier (the count
+    ``run_until_any`` gives ``bfs_batch``)."""
     af = B.dispatch("advance_filter", backend, B.TWOD)
     row_ax, col_ax = axes
     blk, rep = P(row_ax, col_ax), P()
+    b = srcs.shape[0]
 
     @functools.partial(
         shard_map, mesh=mesh,
@@ -715,55 +753,72 @@ def _bfs_2d_impl(ro, ci, row_base, col_base, src, *, n: int, vpr: int,
         out_specs=(rep, rep),
         check_vma=False)
     def run(ro_s, ci_s, rb_s, cb_s, src_v):
-        block_ro, block_ci = ro_s[0, 0], ci_s[0, 0]
+        block_ci = ci_s[0, 0]
         my_rb, my_cb = rb_s[0], cb_s[0]
+        with jax.named_scope("primitive.init"):
+            slots = _block_slots(ro_s[0, 0], block_ci, vpr)
+            lane = jnp.arange(b)
+            labels0 = jnp.full((b, n), -1, jnp.int32).at[lane, src_v].set(0)
+            frontier0 = jnp.zeros((b, n), bool).at[lane, src_v].set(True)
 
         def cond(carry):
-            labels, frontier, it = carry
+            _, frontier, it, _ = carry
             return jnp.any(frontier) & (it <= n)
 
         def body(carry):
-            labels, frontier, it = carry
+            labels, frontier, it, lane_iters = carry
             # fused 2-D advance+filter: row-psum'd chunk discovery with
             # the visited filter applied pre-gather
-            new = af(block_ro, block_ci, frontier, labels >= 0, my_rb,
-                     my_cb, vpr, vpc, axes, tiles)
-            labels = jnp.where(new, it + 1, labels)
-            return labels, new, it + 1
+            new = af(slots, block_ci, frontier, labels >= 0, my_rb, my_cb,
+                     vpr, col_sizes, axes, tiles)
+            with jax.named_scope("op.apply"):
+                labels = jnp.where(new, it + 1, labels)
+            lane_iters = lane_iters + jnp.any(frontier, axis=1)
+            return labels, new, it + 1, lane_iters
 
-        labels0 = jnp.full((n,), -1, jnp.int32).at[src_v].set(0)
-        frontier0 = jnp.zeros((n,), bool).at[src_v].set(True)
-        labels, _, it = jax.lax.while_loop(cond, body,
-                                           (labels0, frontier0,
-                                            jnp.int32(0)))
-        return labels, it
+        labels, _, _, lane_iters = jax.lax.while_loop(
+            cond, body, (labels0, frontier0, jnp.int32(0),
+                         jnp.zeros((b,), jnp.int32)))
+        return labels, lane_iters
 
-    return run(ro, ci, row_base, col_base, src)
+    # the whole program is the level loop: what the compiler hoists out
+    # of it or makes without a name counts under enactor.loop
+    with jax.named_scope("enactor.loop"):
+        return run(ro, ci, row_base, col_base, srcs)
 
 
-def distributed_bfs(pg, src: int, mesh: Mesh, axis="graph",
+def distributed_bfs(pg, srcs, mesh: Mesh, axis="graph",
                     backend: Optional[str] = None,
                     tiles: int = DEFAULT_EXCHANGE_TILES) -> DistBFSResult:
-    """Multi-device BFS (bitmask-exchange advance). A PartitionedGraph
-    runs the 1-D row placement (``mesh`` must have a 1-D axis named
-    ``axis`` whose size equals pg.num_parts); a Partitioned2DGraph runs
-    the vertex-cut 2-D placement (``axis`` may name the (row, col) axis
-    pair; ``tiles`` sets the double-buffer depth of the chunked bitmask
-    exchange). Labels are bit-identical to the single-device ``bfs``
-    either way."""
+    """Multi-device BFS (bitmask-exchange advance). A Partitioned2DGraph
+    runs the vertex-cut 2-D placement (``axis`` may name the (row, col)
+    axis pair; ``tiles`` sets the double-buffer depth of the chunked
+    bitmask exchange) over a (B,) array of sources as ONE program: (B, n)
+    labels and (B,) per-lane iterations. A PartitionedGraph runs the 1-D
+    row placement (``mesh`` must have a 1-D axis named ``axis`` whose
+    size equals pg.num_parts), one source per program. A scalar source
+    gives the (n,) labels and () iterations of that one lane. Labels are
+    bit-identical to the single-device ``bfs_batch`` either way."""
     if isinstance(pg, Partitioned2DGraph):
         axes = _axes_arg(axis)
         _check_mesh(pg, mesh, axes)
         sg = pg.shard(mesh, axes)
+        lanes = jnp.asarray(srcs, jnp.int32)
         labels, it = _bfs_2d_impl(
             sg.row_offsets, sg.col_indices, sg.row_base, sg.col_base,
-            jnp.int32(src), n=pg.n, vpr=pg.vpr, vpc=pg.vpc, mesh=mesh,
-            axes=axes, tiles=max(int(tiles), 1),
+            lanes.reshape(-1), n=pg.n, vpr=pg.vpr, col_sizes=pg.col_sizes,
+            mesh=mesh, axes=axes, tiles=max(int(tiles), 1),
             backend=B.resolve(backend))
+        if lanes.ndim == 0:
+            labels, it = labels[0], it[0]
         return DistBFSResult(labels=labels, iterations=it)
+    if jnp.ndim(srcs):
+        raise ValueError(
+            "the 1-D placement runs one BFS source per program; batch "
+            "sources over the 2-D placement (partition_2d)")
     sg = pg.shard(mesh, axis)            # cached device arrays per mesh
     labels, it = _bfs_dist_impl(
-        sg.row_offsets, sg.col_indices, sg.vertex_base, jnp.int32(src),
+        sg.row_offsets, sg.col_indices, sg.vertex_base, jnp.int32(srcs),
         n=pg.n, vpp=pg.verts_per_part, mesh=mesh, axis=axis,
         backend=B.resolve(backend))
     return DistBFSResult(labels=labels, iterations=it)
@@ -844,12 +899,13 @@ def _sssp_dist_impl(ro, ci, ev, base, src, delta, *, n: int, vpp: int,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n", "vpr", "vpc", "use_delta",
+                   static_argnames=("n", "vpr", "col_sizes", "use_delta",
                                     "mesh", "axes"))
 def _sssp_2d_impl(ro, ci, ev, row_base, col_base, src, delta, *, n: int,
-                  vpr: int, vpc: int, use_delta: bool, mesh: Mesh,
+                  vpr: int, col_sizes: tuple, use_delta: bool, mesh: Mesh,
                   axes: tuple):
     row_ax, col_ax = axes
+    vpc = max(col_sizes)
     blk, rep = P(row_ax, col_ax), P()
     inf = jnp.float32(jnp.inf)
 
@@ -878,7 +934,7 @@ def _sssp_2d_impl(ro, ci, ev, row_base, col_base, src, delta, *, n: int,
             chunk = chunk.at[tgt].min(jnp.where(active, cand_v, inf),
                                       mode="drop")
             chunk = jax.lax.pmin(chunk, row_ax)
-            cand = _gather_chunks(chunk, col_ax, n)
+            cand = _gather_chunks(chunk, col_ax, col_sizes)
             new_dist = jnp.minimum(dist, cand)
             improved = new_dist < dist
             thresh = (bucket.astype(jnp.float32) + 1.0) * delta_v
@@ -933,7 +989,6 @@ def distributed_sssp(pg, src: int, mesh: Mesh, axis="graph",
             from .primitives.sssp import _auto_delta
             delta = _auto_delta(pg.source)
         else:
-            import numpy as np
             real = np.asarray(pg.col_indices) >= 0
             mean_w = float(np.asarray(pg.edge_values)[real].mean())
             delta = mean_w * max(pg.m / max(pg.n, 1), 1.0) / 2.0
@@ -945,8 +1000,8 @@ def distributed_sssp(pg, src: int, mesh: Mesh, axis="graph",
         dist, it = _sssp_2d_impl(
             sg.row_offsets, sg.col_indices, sg.edge_values, sg.row_base,
             sg.col_base, jnp.int32(src), jnp.float32(delta),
-            n=pg.n, vpr=pg.vpr, vpc=pg.vpc, use_delta=use_delta,
-            mesh=mesh, axes=axes)
+            n=pg.n, vpr=pg.vpr, col_sizes=pg.col_sizes,
+            use_delta=use_delta, mesh=mesh, axes=axes)
         return DistSSSPResult(dist=dist, iterations=it)
     sg = pg.shard(mesh, axis)
     dist, it = _sssp_dist_impl(
@@ -1143,11 +1198,16 @@ def distributed_reach(pg, srcs, k: int = 3, *,
 
 
 def exchange_bytes_per_step(pg, primitive: str = "bfs",
-                            tiles: int = DEFAULT_EXCHANGE_TILES) -> int:
+                            tiles: int = DEFAULT_EXCHANGE_TILES,
+                            batch: int = 1) -> int:
     """Analytic bytes exchanged PER DEVICE in one BSP step of
     ``primitive`` under ``pg``'s placement, with the standard ring
     cost model (an all-reduce of b bytes moves 2·(p−1)/p·b per device;
-    an all-gather of b-byte shards moves (p−1)·b).
+    an all-gather of b-byte shards moves (p−1)·b). ``batch`` is the
+    number of sources a step serves: bfs and sssp exchange once per
+    source (in one program's (B, …) chunks for the 2-D bfs, in one
+    program per source elsewhere), so their bytes scale with it; cc and
+    pagerank answer any batch with one whole-graph run.
 
     1-D exchanges are n-proportional (the replicated-vector tax the
     2-D cut removes): bfs/sssp/cc all-reduce an (n,) candidate vector,
@@ -1159,15 +1219,16 @@ def exchange_bytes_per_step(pg, primitive: str = "bfs",
     into arbitrary component ids, so its exchange stays (n,) on any
     mesh — reported as-is, not hidden."""
     tiles = max(int(tiles), 1)
+    batch = max(int(batch), 1)
     n = pg.n
     if isinstance(pg, Partitioned2DGraph):
         r, c = pg.rows, pg.cols
         if primitive == "bfs":
-            return int(tiles * 2 * (r - 1) / r * pg.vpc
-                       + (c - 1) * pg.vpc)
+            return int(batch * (tiles * 2 * (r - 1) / r * pg.vpc
+                                + (c - 1) * pg.vpc))
         if primitive == "sssp":
-            return int((2 * (r - 1) / r * pg.vpc
-                        + (c - 1) * pg.vpc) * 4)
+            return int(batch * (2 * (r - 1) / r * pg.vpc
+                                + (c - 1) * pg.vpc) * 4)
         if primitive == "cc":
             p = r * c
             return int(2 * (p - 1) / p * n * 4)
@@ -1176,7 +1237,9 @@ def exchange_bytes_per_step(pg, primitive: str = "bfs",
                        + (r - 1) * pg.vpr * 4)
         raise ValueError(f"unknown primitive {primitive!r}")
     p = pg.num_parts
-    if primitive in ("bfs", "sssp", "cc"):
+    if primitive in ("bfs", "sssp"):
+        return int(batch * 2 * (p - 1) / p * n * 4)
+    if primitive == "cc":
         return int(2 * (p - 1) / p * n * 4)
     if primitive == "pagerank":
         return int((p - 1) / p * n * 4)

@@ -16,13 +16,17 @@ core/distributed.py.
 
 2-D vertex-cut partition (placement="2d"): edges are blocked on an R×C
 device mesh — device (i, j) holds the edges whose source lies in row
-chunk i (ceil(n/R) vertices) and whose destination lies in column chunk
-j (ceil(n/C) vertices). Every vertex has one designated owner device
+chunk i and whose destination lies in column chunk j. Chunks are
+contiguous id ranges cut where the running out-degree (rows) or
+in-degree (columns) sum crosses each 1/R (1/C) share of the edges, so
+every block holds about a 1/(R·C) share of them whatever ids the hubs
+carry; chunk arrays are padded to the widest chunk (vpr, vpc). Every
+vertex has one designated owner device
 (``owner_of``); the other devices touching it hold *mirrors* (the
 vertex-cut replication the balance stats account). Frontier exchange
 then shrinks from the 1-D all-reduce over (n,) to a psum along the R
-row devices of one ceil(n/C) column chunk plus an all-gather of the C
-chunks — the comm-volume win measured by benchmarks/distributed_scale.
+row devices of one column chunk plus an all-gather of the C chunks —
+the comm-volume win measured by benchmarks/distributed_scale.
 
 Containers:
 
@@ -47,6 +51,7 @@ from dataclasses import dataclass
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
@@ -331,6 +336,22 @@ class ShardedGraph:
 # ---------------------------------------------------------------------------
 
 
+def _chunk_sizes(base, n: int) -> tuple:
+    return tuple(int(x) for x in np.diff(np.append(np.asarray(base), n)))
+
+
+def unpad_chunks(v, width: int, sizes: tuple, axis: int = -1):
+    """The chunks laid out ``width`` apart along ``axis`` of ``v``, each
+    cut to its first ``sizes[k]`` entries and concatenated: the global
+    (sum(sizes),) vertex axis (static slices, no gather)."""
+    cut = jax.lax.slice_in_dim
+    if all(s == width for s in sizes[:-1]):
+        return cut(v, 0, sum(sizes), axis=axis)
+    return jnp.concatenate(
+        [cut(v, k * width, k * width + s, axis=axis)
+         for k, s in enumerate(sizes)], axis=axis)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass(frozen=True)
 class Blocks2D:
@@ -352,18 +373,39 @@ class Blocks2D:
     epos: jax.Array       # (R, C, be) edge position in the row chunk
     chunk_ro: jax.Array   # (R, C, vpr+1) row-chunk CSR offsets (col-repl.)
     chunk_emax: int       # static: max edges of any row chunk
+    row_sizes: tuple      # static: vertices of each row chunk
 
     def tree_flatten(self):
-        return (self.cols, self.epos, self.chunk_ro), (self.chunk_emax,)
+        return ((self.cols, self.epos, self.chunk_ro),
+                (self.chunk_emax, self.row_sizes))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*children, *aux)
 
 
+def _edge_balanced_bounds(degrees: np.ndarray, parts: int) -> np.ndarray:
+    """(parts+1,) ascending vertex-id cuts of ``parts`` contiguous chunks
+    holding about equal shares of the degree sum: each cut sits at the
+    vertex boundary whose running sum lies nearest its share (ties to
+    the lower). A graph without edges gets the uniform cut."""
+    n = len(degrees)
+    cum = np.concatenate([[0], np.cumsum(degrees, dtype=np.int64)])
+    if cum[-1] == 0:
+        return np.minimum(np.arange(parts + 1) * -(-n // parts), n)
+    share = cum[-1] * np.arange(1, parts) / parts
+    hi = np.searchsorted(cum, share)          # first cut at or past it
+    lo = np.maximum(hi - 1, 0)
+    cut = np.where(share - cum[lo] <= cum[hi] - share, lo, hi)
+    return np.concatenate([[0], cut, [n]]).astype(np.int64)
+
+
 def _slice_blocks(ro: np.ndarray, ci: np.ndarray, ev: Optional[np.ndarray],
-                  n: int, rows: int, cols: int, vpr: int, vpc: int):
-    """Block one CSR-like structure on the R×C vertex cut.
+                  row_bounds: np.ndarray, col_bounds: np.ndarray,
+                  vpr: int, vpc: int):
+    """Block one CSR-like structure on the R×C vertex cut: row chunk i
+    is the ids [row_bounds[i], row_bounds[i+1]), column chunk j
+    [col_bounds[j], col_bounds[j+1]); ``vpr``/``vpc`` are the widest.
 
     Returns stacked (R, C, …) block arrays (rebased offsets, global
     column ids padded with -1, values, row-chunk edge positions), the
@@ -371,6 +413,7 @@ def _slice_blocks(ro: np.ndarray, ci: np.ndarray, ev: Optional[np.ndarray],
     accounting tables (per-block edge counts / ELL widths / distinct
     vertices materialized per block — the mirror table)."""
     from .graph import ell_width_for
+    rows, cols = len(row_bounds) - 1, len(col_bounds) - 1
     blocks: list = []
     chunk_ros = []
     be_max, chunk_emax = 1, 1
@@ -378,8 +421,7 @@ def _slice_blocks(ro: np.ndarray, ci: np.ndarray, ev: Optional[np.ndarray],
     block_ell = np.ones((rows, cols), np.int64)
     mirrors = np.zeros((rows, cols), np.int64)
     for i in range(rows):
-        lo_v = min(i * vpr, n)
-        hi_v = min((i + 1) * vpr, n)
+        lo_v, hi_v = int(row_bounds[i]), int(row_bounds[i + 1])
         lo_e, hi_e = int(ro[lo_v]), int(ro[hi_v])
         cro = (ro[lo_v:hi_v + 1] - ro[lo_v]).astype(np.int64)
         pad_v = vpr - (hi_v - lo_v)
@@ -395,7 +437,7 @@ def _slice_blocks(ro: np.ndarray, ci: np.ndarray, ev: Optional[np.ndarray],
                            np.diff(ro[lo_v:hi_v + 1]))
         row_blocks = []
         for j in range(cols):
-            sel = (c_ci >= j * vpc) & (c_ci < (j + 1) * vpc)
+            sel = (c_ci >= col_bounds[j]) & (c_ci < col_bounds[j + 1])
             cnt = np.bincount(row_of[sel], minlength=vpr)[:vpr]
             b_ro = np.concatenate(
                 [[0], np.cumsum(cnt)]).astype(np.int32)
@@ -472,8 +514,8 @@ class Partitioned2DGraph:
     m: int
     rows: int                    # R (mesh rows)
     cols: int                    # C (mesh columns)
-    vpr: int                     # ceil(n / R): row-chunk vertices
-    vpc: int                     # ceil(n / C): column-chunk vertices
+    vpr: int                     # widest row chunk's vertices
+    vpc: int                     # widest column chunk's vertices
     row_offsets: np.ndarray      # (R, C, vpr+1) rebased block CSR
     col_indices: np.ndarray      # (R, C, be) global dst ids, pad -1
     edge_values: Optional[np.ndarray]
@@ -507,8 +549,19 @@ class Partitioned2DGraph:
         the device whose row chunk AND column chunk both contain v;
         every other device touching v holds a mirror."""
         v = np.asarray(v)
-        return (np.minimum(v // self.vpr, self.rows - 1),
-                np.minimum(v // self.vpc, self.cols - 1))
+        return (np.searchsorted(self.row_base, v, side="right") - 1,
+                np.searchsorted(self.col_base, v, side="right") - 1)
+
+    @property
+    def row_sizes(self) -> tuple:
+        """Vertices of each row chunk (static: the 2-D providers
+        unpad row-chunk outputs with it)."""
+        return _chunk_sizes(self.row_base, self.n)
+
+    @property
+    def col_sizes(self) -> tuple:
+        """Vertices of each column chunk."""
+        return _chunk_sizes(self.col_base, self.n)
 
     def balance(self) -> dict:
         """2-D load accounting: per-block edge counts, both imbalance
@@ -517,9 +570,7 @@ class Partitioned2DGraph:
         smaller exchanges)."""
         edges = self.block_edges
         mean_e = max(edges.sum() / max(self.num_parts, 1), 1e-9)
-        verts = [int(min((i + 1) * self.vpr, self.n)
-                     - min(i * self.vpr, self.n))
-                 for i in range(self.rows)]
+        verts = list(self.row_sizes)
         mean_v = max(sum(verts) / max(self.rows, 1), 1e-9)
         return {
             "parts": self.num_parts,
@@ -574,6 +625,7 @@ class Partitioned2DGraph:
             col_base=_placer(mesh, P(axes[1]))(self.col_base),
             n=self.n, m=self.m, rows=self.rows, cols=self.cols,
             vpr=self.vpr, vpc=self.vpc,
+            row_sizes=self.row_sizes, col_sizes=self.col_sizes,
             chunk_emax=self.chunk_emax,
             csc_chunk_emax=self.csc_chunk_emax,
             mesh=mesh, axes=axes,
@@ -617,6 +669,8 @@ class Sharded2DGraph:
     cols: int
     vpr: int
     vpc: int
+    row_sizes: tuple                  # vertices of each row chunk
+    col_sizes: tuple                  # vertices of each column chunk
     chunk_emax: int
     csc_chunk_emax: int
     mesh: object
@@ -642,9 +696,9 @@ class Sharded2DGraph:
                     self.csc_edge_pos, self.csc_chunk_offsets,
                     self.row_base, self.col_base)
         aux = (self.n, self.m, self.rows, self.cols, self.vpr, self.vpc,
-               self.chunk_emax, self.csc_chunk_emax, self.mesh,
-               self.axes, self.ell_width, self.csc_ell_width,
-               self.source_plan)
+               self.row_sizes, self.col_sizes, self.chunk_emax,
+               self.csc_chunk_emax, self.mesh, self.axes, self.ell_width,
+               self.csc_ell_width, self.source_plan)
         return children, aux
 
     @classmethod
@@ -679,20 +733,22 @@ class Sharded2DGraph:
     def col_store(self) -> Blocks2D:
         return Blocks2D(cols=self.col_indices, epos=self.edge_pos,
                         chunk_ro=self.chunk_offsets,
-                        chunk_emax=self.chunk_emax)
+                        chunk_emax=self.chunk_emax,
+                        row_sizes=self.row_sizes)
 
     @property
     def csc_store(self) -> Blocks2D:
         return Blocks2D(cols=self.csc_indices, epos=self.csc_edge_pos,
                         chunk_ro=self.csc_chunk_offsets,
-                        chunk_emax=self.csc_chunk_emax)
+                        chunk_emax=self.csc_chunk_emax,
+                        row_sizes=self.row_sizes)
 
     @property
     def degrees(self) -> jax.Array:
         """Global out-degree vector (n,) from the row-chunk offsets
         (pad rows repeat the final offset ⇒ degree 0)."""
         local = self.chunk_offsets[:, 0, 1:] - self.chunk_offsets[:, 0, :-1]
-        return local.reshape(-1)[:self.n]
+        return unpad_chunks(local.reshape(-1), self.vpr, self.row_sizes)
 
 
 def partition_2d(graph: Graph, rows: int, cols: int) -> Partitioned2DGraph:
@@ -704,11 +760,14 @@ def partition_2d(graph: Graph, rows: int, cols: int) -> Partitioned2DGraph:
     ev = (np.asarray(graph.edge_values, np.float32)
           if graph.edge_values is not None else None)
     n = graph.num_vertices
-    vpr = -(-n // rows)
-    vpc = -(-n // cols)
+    row_bounds = _edge_balanced_bounds(np.diff(ro), rows)
+    col_bounds = _edge_balanced_bounds(
+        np.bincount(ci, minlength=n), cols)
+    vpr = max(int(np.diff(row_bounds).max()), 1)
+    vpc = max(int(np.diff(col_bounds).max()), 1)
     (b_ro, b_ci, b_ev, b_ep, chunk_ro, chunk_emax,
      block_edges, block_ell, mirrors) = _slice_blocks(
-        ro, ci, ev, n, rows, cols, vpr, vpc)
+        ro, ci, ev, row_bounds, col_bounds, vpr, vpc)
     kw: dict = {}
     if graph.has_csc:
         (c_ro, c_ci, c_ev, c_ep, c_cro, c_emax, _, _, _) = _slice_blocks(
@@ -716,7 +775,7 @@ def partition_2d(graph: Graph, rows: int, cols: int) -> Partitioned2DGraph:
             np.asarray(graph.csc_cols()),
             (np.asarray(graph.csc_edge_values, np.float32)
              if graph.csc_edge_values is not None else None),
-            n, rows, cols, vpr, vpc)
+            row_bounds, col_bounds, vpr, vpc)
         kw = dict(csc_row_offsets=c_ro, csc_col_indices=c_ci,
                   csc_edge_values=c_ev, csc_edge_pos=c_ep,
                   csc_chunk_offsets=c_cro, csc_chunk_emax=c_emax)
@@ -724,7 +783,7 @@ def partition_2d(graph: Graph, rows: int, cols: int) -> Partitioned2DGraph:
         n=n, m=graph.num_edges, rows=rows, cols=cols, vpr=vpr, vpc=vpc,
         row_offsets=b_ro, col_indices=b_ci, edge_values=b_ev,
         edge_pos=b_ep, chunk_offsets=chunk_ro, chunk_emax=chunk_emax,
-        row_base=(np.arange(rows) * vpr).astype(np.int32),
-        col_base=(np.arange(cols) * vpc).astype(np.int32),
+        row_base=row_bounds[:-1].astype(np.int32),
+        col_base=col_bounds[:-1].astype(np.int32),
         block_edges=block_edges, block_ell_width=block_ell,
         mirrors=mirrors, source=graph, **kw)

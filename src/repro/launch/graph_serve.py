@@ -226,19 +226,23 @@ def _run_kind(g, kind: str, srcs: np.ndarray, backend: str, hops: int,
 
 def make_sharded_runner(pg, mesh, axis="graph"):
     """Mesh-backed query runner: every kind is served from the 1-D (or
-    2-D vertex-cut) partition built once at startup. Traversal kinds
-    (bfs/sssp) run one cached distributed trace per query lane (the
-    trace is keyed on the partition shapes + mesh, so lanes reuse it);
-    algebraic kinds run the placement's "spmm"/"spmv" providers through
-    the unchanged primitives. Results bit-match the single-device
-    runner, so the oracle validation path needs no sharded variant."""
+    2-D vertex-cut) partition built once at startup. On the 2-D cut a
+    bfs flush runs as ONE batched distributed program over all its
+    lanes; sssp, and bfs on the 1-D partition, run one cached
+    distributed trace per distinct source (the trace is keyed on the
+    partition shapes + mesh, so lanes reuse it); algebraic kinds run the
+    placement's "spmm"/"spmv" providers through the unchanged
+    primitives. Results bit-match the single-device runner, so the
+    oracle validation path needs no sharded variant."""
     import jax.numpy as jnp
 
     from repro.core.distributed import (_shard_any, distributed_bfs,
                                         distributed_sssp)
+    from repro.core.partition import Partitioned2DGraph
     from repro.core.primitives import pagerank, reach_batch
 
     sg = _shard_any(pg, mesh, axis)
+    batched_bfs = isinstance(pg, Partitioned2DGraph)
 
     def _per_source(srcs, one):
         # padding lanes repeat the final real query — run each distinct
@@ -255,8 +259,11 @@ def make_sharded_runner(pg, mesh, axis="graph"):
     def run(kind: str, srcs: np.ndarray, backend: str, hops: int):
         zeros = np.zeros(len(srcs), np.int64)
         if kind == "bfs":
-            out = _per_source(srcs, lambda s: distributed_bfs(
-                pg, s, mesh, axis, backend=backend).labels)
+            out = (distributed_bfs(pg, np.asarray(srcs), mesh, axis,
+                                   backend=backend).labels
+                   if batched_bfs else
+                   _per_source(srcs, lambda s: distributed_bfs(
+                       pg, s, mesh, axis, backend=backend).labels))
             jax.block_until_ready(out)
             return out, zeros           # dense bitmask advance: no caps,
         if kind == "sssp":              # so no overflow to report
@@ -850,15 +857,16 @@ def _main(args):
                  f"edge imbalance {bal['edge_imbalance']}x, "
                  f"vertex imbalance {bal['vertex_imbalance']}x")
         if metrics is not None:
-            # analytic per-BSP-step exchange volume (the PR 7 comm
-            # model) per served traversal kind — the distributed
+            # analytic per-BSP-step exchange volume of a flush (the
+            # comm model, batch lanes) per served kind — the distributed
             # counterpart of the single-device telemetry columns
             from repro.core.distributed import exchange_bytes_per_step
             for kind in (kinds or [args.primitive]):
                 try:
                     metrics.gauge(
                         "exchange_bytes_per_step",
-                        exchange_bytes_per_step(pg, kind),
+                        exchange_bytes_per_step(pg, kind,
+                                                batch=args.batch),
                         help="analytic per-device exchange bytes per "
                              "BSP step (comm model)", kind=kind)
                 except (KeyError, ValueError):

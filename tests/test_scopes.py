@@ -96,3 +96,51 @@ def test_bfs_rungs_and_mixed_step_are_tagged():
     assert {"enactor.loop", "enactor.select_lanes", "enactor.tier",
             "enactor.direction", "op.advance_filter", "op.pull",
             "op.apply", "primitive.init", "primitive.result"} <= leaves
+
+
+def _bfs_2d_hlo() -> str:
+    """The batched 2-D BFS program on a 2×2 mesh of four fake host
+    devices, compiled in a subprocess (the test process keeps one device)."""
+    import os
+    import subprocess
+    import textwrap
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(repo, "src"))
+    code = textwrap.dedent("""
+        import numpy as np, jax, jax.numpy as jnp
+        from jax.sharding import Mesh
+        from repro.core import distributed as D, graph as G
+        from repro.core.partition import partition_2d
+        pg = partition_2d(G.rmat(9, 8, seed=0), 2, 2)
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                    ("row", "col"))
+        sg = pg.shard(mesh)
+        print(D._bfs_2d_impl.lower(
+            sg.row_offsets, sg.col_indices, sg.row_base, sg.col_base,
+            jnp.arange(8, dtype=jnp.int32), n=pg.n, vpr=pg.vpr,
+            col_sizes=pg.col_sizes, mesh=mesh, axes=("row", "col"), tiles=2,
+            backend="xla").compile().as_text())
+    """)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr
+    return out.stdout
+
+
+def test_2d_bfs_ops_carry_a_scope_and_collectives_are_the_exchange():
+    text = _bfs_2d_hlo()
+    resolve = hlo_op_names(text)
+    names = _device_instructions(text)
+    leaves = [scope_of(resolve(n))[0] for n in names]
+    unscoped = [n for n, leaf in zip(names, leaves) if leaf == NONE]
+    assert len(names) > 10
+    assert len(unscoped) <= 0.05 * len(names), unscoped
+    assert {"op.advance_filter", "op.exchange", "op.apply",
+            "enactor.loop", "primitive.init"} <= set(leaves)
+    collectives = [n for n in names
+                   if re.match(r"(all-reduce|all-gather)", n)]
+    assert collectives
+    assert {scope_of(resolve(n))[0] for n in collectives} == \
+        {"op.exchange"}

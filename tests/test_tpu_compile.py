@@ -117,6 +117,38 @@ def test_xla_main_path_compiles_for_v5e(graph_shapes, one_chip, program):
     assert mem.argument_size_in_bytes > 0
 
 
+def test_batched_2d_bfs_compiles_for_a_2x2_v5e(topo):
+    """The batched 2-D BFS over a 2×2 vertex cut of the described host:
+    one program, its row psum and column all-gather inside."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import distributed as D
+    from repro.core.partition import partition_2d
+    pg = partition_2d(G.rmat(SCALE, 8, seed=0), 2, 2)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("row", "col"))
+
+    def sds(a, spec):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    blk = P("row", "col")
+    compiled = D._bfs_2d_impl.lower(
+        sds(pg.row_offsets, blk), sds(pg.col_indices, blk),
+        sds(pg.row_base, P("row")), sds(pg.col_base, P("col")),
+        jax.ShapeDtypeStruct((BATCH,), jnp.int32,
+                             sharding=NamedSharding(mesh, P())),
+        n=pg.n, vpr=pg.vpr, col_sizes=pg.col_sizes, mesh=mesh,
+        axes=("row", "col"),
+        tiles=D.DEFAULT_EXCHANGE_TILES, backend=B.XLA).compile()
+    text = compiled.as_text()
+    assert re.search(r"\ball-reduce(-start)?\(", text)
+    assert re.search(r"\ball-gather(-start)?\(", text)
+    assert compiled.memory_analysis().argument_size_in_bytes > 0
+
+
 # ---------------------------------------------------------------------------
 # Pallas graph kernels: refused by the TPU lowering today
 # ---------------------------------------------------------------------------
